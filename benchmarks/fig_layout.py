@@ -8,16 +8,14 @@ vertex-reduce round under (a) edges sharded across ALL axes including pod —
 cross-pod psum carries the O(n) vertex vector per axis; (b) the engine's
 layout where the pod axis only ever reduces O(n) words once.
 
-On 8 fake CPU devices (2 pods × 4); the metric is compile-derived bytes,
-not wall time.
+On 8 fake CPU devices (2 pods × 4) in a host-CPU child process; the
+metric is compile-derived bytes, not wall time.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import time
+
+from .cpu_child import PLATFORM, run_cpu_child
 
 
 def run():
@@ -48,24 +46,13 @@ for name, shape, axes in [
 print(json.dumps(out))
 """
     t0 = time.perf_counter()
-    r = subprocess.run(
-        [sys.executable, "-c", code],
-        capture_output=True,
-        text=True,
-        env={**os.environ, "PYTHONPATH": "src"},
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    )
+    data = run_cpu_child(code)
     dt = time.perf_counter() - t0
-    line = [l for l in r.stdout.splitlines() if l.startswith("{")]
-    if not line:
-        return [dict(name="fig_layout", us_per_call=dt * 1e6,
-                     derived="FAILED: " + r.stderr[-200:])]
-    data = json.loads(line[-1])
     return [
         dict(
             name=f"fig_layout_{k}",
             us_per_call=dt * 1e6 / max(len(data), 1),
-            derived=f"collective_bytes_per_round={v}",
+            derived=f"collective_bytes_per_round={v} platform={PLATFORM}",
         )
         for k, v in data.items()
     ]

@@ -1,5 +1,5 @@
-"""Kernel microbenchmarks: the Pallas kernels (interpret mode on this CPU
-container; on TPU the same call sites compile natively) against their
+"""Kernel microbenchmarks: the Pallas kernels (interpret mode on CPU; on a
+TPU only the streamed decode lowers natively, the rest refuse) against their
 pure-jnp references, plus the frontier-sweep row demonstrating the
 chunked-mode kernel's PSAM read model: streamed bytes proportional to the
 live (frontier-owned) blocks, never to NB."""

@@ -5,12 +5,11 @@ dry-run's compiled artifacts.
   memory term     = per-device HLO bytes / HBM bandwidth
   collective term = per-device collective bytes / ICI link bandwidth
 
-Hardware model (TPU v5e-class, per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI (one effective link per collective hop — conservative).
-The numbers come from the tuning table's ``hardware`` section
-(``repro.tuning.hardware_model``) — the SAME description the calibration
-pass records — so roofline terms and measured-cost autotuning can never
-drift onto two divergent hardware models.
+Hardware model: the published peaks of one TPU v5e chip
+(``repro.tuning.hardware_model("TPU v5 lite")``: 197 TFLOP/s bf16, 819 GB/s
+HBM, 50 GB/s per ICI link, one link per collective hop).  The records are
+compiled on host CPU devices, so these terms are a model of the chip, never
+a measurement on it.
 
 Also reported: MODEL_FLOPS / HLO_FLOPs ("useful fraction" — catches remat
 and redundancy waste) and the dominant bottleneck term.
@@ -23,7 +22,7 @@ import os
 
 from repro.tuning import hardware_model
 
-_HW = hardware_model()
+_HW = hardware_model("TPU v5 lite")
 PEAK_FLOPS = _HW["peak_flops"]
 HBM_BW = _HW["hbm_bw"]
 ICI_BW = _HW["ici_bw"]

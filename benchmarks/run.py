@@ -26,6 +26,9 @@ Prints ``name,us_per_call,derived`` CSV lines.
 from __future__ import annotations
 
 import argparse
+import sys
+
+from repro.runtime import enable_compile_cache
 
 
 def main() -> None:
@@ -41,6 +44,7 @@ def main() -> None:
         "sage.round / sage.shard_combine named scopes)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     from . import (fig1_suite, fig7_dram_nvram, fig_layout, kernels_micro,
                    table4_filter, table5_edgemap, table_autotune,
@@ -95,13 +99,10 @@ def main() -> None:
         "fig_layout": fig_layout.run,
         "fig7_dram_nvram": fig7_dram_nvram.run,
     }
-    try:
-        from . import roofline
+    from . import roofline
 
-        if roofline.load_records():
-            benches["roofline"] = roofline.run
-    except Exception:
-        pass
+    if roofline.load_records():
+        benches["roofline"] = roofline.run
 
     only = set(args.only.split(",")) if args.only else None
     import contextlib
@@ -115,6 +116,7 @@ def main() -> None:
     else:
         annotate = None
         session = contextlib.nullcontext()
+    failed = []
     with session:
         print("name,us_per_call,derived")
         for name, fn in benches.items():
@@ -132,7 +134,12 @@ def main() -> None:
                         flush=True,
                     )
             except Exception as e:  # noqa: BLE001
+                # keep going so one run reports every bench, then fail
                 print(f"{name},-1,ERROR: {type(e).__name__}: {e}", flush=True)
+                failed.append(name)
+    if failed:
+        print(f"benchmarks failed: {','.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

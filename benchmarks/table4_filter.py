@@ -16,16 +16,14 @@ filtered read model (``charge_edgemap_planned(filter_live_blocks=...)``).
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
 
 from repro.algorithms.substructure import orientation_filter, triangle_count
 from repro.data import rmat_graph
+
+from .cpu_child import PLATFORM, run_cpu_child
 
 
 def run(n=2048, m=16384, block_sizes=(32, 64, 128, 256)):
@@ -144,29 +142,8 @@ def run_planned(n=512, m=4096):
             )
         )
 
-    # 4-shard mesh columns run in a subprocess so the fake-device XLA flag
-    # doesn't leak into this process
-    r = subprocess.run(
-        [sys.executable, "-c", _MESH_CODE, str(n), str(m)],
-        capture_output=True,
-        text=True,
-        env={
-            **os.environ,
-            "PYTHONPATH": "src" + os.pathsep + os.environ.get("PYTHONPATH", ""),
-        },
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    )
-    lines = [ln for ln in r.stdout.splitlines() if ln.startswith("{")]
-    if not lines:
-        rows.append(
-            dict(
-                name="table4_filtered_mesh4",
-                us_per_call=-1,
-                derived="FAILED: " + r.stderr[-200:].replace("\n", " "),
-            )
-        )
-        return rows
-    mesh_us = json.loads(lines[-1])
+    # 4-shard mesh columns run in a host-CPU child process
+    mesh_us = run_cpu_child(_MESH_CODE, n, m)
     for label in ["csr", "compressed"]:
         cost = PSAMCost()
         backend = c if label == "compressed" else g
@@ -177,7 +154,8 @@ def run_planned(n=512, m=4096):
                 us_per_call=mesh_us[label],
                 derived=(
                     f"shards=4 live_blocks={live}/{g.num_blocks} "
-                    f"psam_filtered_read_words={cost.large_reads}"
+                    f"psam_filtered_read_words={cost.large_reads} "
+                    f"platform={PLATFORM}"
                 ),
             )
         )

@@ -7,16 +7,13 @@ compressed/raw wall-time ratio, and the PSAM per-shard read model
 (``charge_edgemap_planned``) — the honest bytes-off-large-memory contrast
 for the distributed path.  ``--full`` runs RMAT-20 (n = 2²⁰).
 
-Runs in a subprocess so the fake-device XLA flag doesn't leak into the
-parent process.
+Runs in a host-CPU child process (``benchmarks.cpu_child``), so the
+fake-device XLA flag doesn't leak into the parent and the child never
+claims the parent's accelerator; every row says ``platform=cpu``.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import time
+from .cpu_child import PLATFORM, run_cpu_child
 
 CODE = r"""
 import os
@@ -60,20 +57,7 @@ print(json.dumps(out))
 
 
 def run(n=4096, m=16384):
-    t0 = time.perf_counter()
-    r = subprocess.run(
-        [sys.executable, "-c", CODE, str(n), str(m)],
-        capture_output=True,
-        text=True,
-        env={**os.environ, "PYTHONPATH": "src"},
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    )
-    dt = time.perf_counter() - t0
-    lines = [ln for ln in r.stdout.splitlines() if ln.startswith("{")]
-    if not lines:
-        return [dict(name="table_distributed", us_per_call=dt * 1e6,
-                     derived="FAILED: " + r.stderr[-200:])]
-    d = json.loads(lines[-1])
+    d = run_cpu_child(CODE, n, m)
     rows = []
     for label in ["raw", "compressed"]:
         rows.append(
@@ -83,7 +67,8 @@ def run(n=4096, m=16384):
                 derived=(
                     f"edges_per_s_per_shard={d[label]['edges_per_s_per_shard']:.0f} "
                     f"psam_read_words={d[label]['psam_read_words']} "
-                    f"shards={d['shards']} n={d['n']} m={d['m']}"
+                    f"shards={d['shards']} n={d['n']} m={d['m']} "
+                    f"platform={PLATFORM}"
                 ),
             )
         )
@@ -95,7 +80,7 @@ def run(n=4096, m=16384):
                 f"us_ratio={d['compressed']['us'] / max(d['raw']['us'], 1e-9):.2f} "
                 f"psam_read_saving="
                 f"{d['raw']['psam_read_words'] / max(d['compressed']['psam_read_words'], 1):.2f}x "
-                f"compression_ratio={d['ratio']:.2f}x"
+                f"compression_ratio={d['ratio']:.2f}x platform={PLATFORM}"
             ),
         )
     )

@@ -17,9 +17,11 @@ from repro.algorithms import kcore, maximal_matching, triangle_count
 from repro.algorithms.substructure import orientation_filter
 from repro.core import PSAMCost, make_plan
 from repro.data import rmat_graph
+from repro.runtime import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     key = jax.random.PRNGKey(7)
     g = rmat_graph(n=1024, m=8192, seed=7, block_size=64)
     plan = make_plan(g, strategy="auto")

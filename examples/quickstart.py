@@ -13,10 +13,12 @@ import jax.numpy as jnp
 from repro.algorithms import bfs, connectivity, kcore, pagerank, triangle_count
 from repro.core import PSAMCost, filter_edges_pred, make_filter, make_plan
 from repro.data import rmat_graph
+from repro.runtime import enable_compile_cache
 from repro.serving import QueryEngine
 
 
 def main():
+    enable_compile_cache()
     key = jax.random.PRNGKey(0)
     g = rmat_graph(n=2048, m=16384, weighted=True, seed=42, block_size=64)
     print(f"graph: n={g.n} m={g.m} blocks={g.num_blocks} (F_B={g.block_size})")

@@ -10,9 +10,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import transformer_lm as lm
+from repro.runtime import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)

@@ -13,6 +13,7 @@ import jax
 
 from repro.launch.train import TrainConfig, Trainer
 from repro.models import transformer_lm as lm
+from repro.runtime import enable_compile_cache
 
 
 def model_100m():
@@ -31,6 +32,7 @@ def model_tiny():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--full", action="store_true")

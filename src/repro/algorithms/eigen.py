@@ -30,29 +30,32 @@ def pagerank(
     n = g.n
     if plan is not None:
         g = plan.prepare(g)
+    # degree vectors ride in the loop state rather than the callbacks'
+    # closures: a pipelined round_loop runs the callbacks inside shard_map,
+    # which refuses captured arrays that were placed under a mesh context
     deg = jnp.maximum(g.degrees, 1).astype(jnp.float32)
     dangling = g.degrees == 0
     full_mask = jnp.ones(n, dtype=bool)
     pr0 = jnp.full(n, 1.0 / n, jnp.float32)
 
     def sweep_inputs(state):
-        pr, _, _ = state
+        pr, _, _, deg, dangling = state
         contrib = jnp.where(dangling, 0.0, pr / deg)
         return state, full_mask, contrib
 
     def epilogue(state, s, _touched):
-        pr, it, _ = state
+        pr, it, _, deg, dangling = state
         dangling_mass = jnp.sum(jnp.where(dangling, pr, 0.0))
         new = (1.0 - damping) / n + damping * (s + dangling_mass / n)
         err = jnp.sum(jnp.abs(new - pr))
-        return new, it + 1, err
+        return new, it + 1, err, deg, dangling
 
     def cond(state):
-        _, it, err = state
+        _, it, err, _, _ = state
         return (err > eps) & (it < max_iters)
 
-    pr, iters, _ = round_loop(
-        g, (pr0, jnp.int32(0), jnp.float32(jnp.inf)),
+    pr, iters, _, _, _ = round_loop(
+        g, (pr0, jnp.int32(0), jnp.float32(jnp.inf), deg, dangling),
         sweep_inputs=sweep_inputs, epilogue=epilogue, cond_fn=cond,
         monoid="sum", plan=plan, mode="dense",
     )

@@ -1,50 +1,21 @@
-"""JAX version-compat shims.
+"""Mesh helpers shared by every mesh construction and mesh-context entry.
 
-The codebase targets the explicit-axis-types mesh API (``jax.sharding.AxisType``
-+ ``jax.set_mesh``); the pinned install (0.4.37, see requirements.txt)
-predates both while already providing ``jax.make_mesh`` and the legacy Mesh
-context manager.  Every mesh construction and mesh-context entry goes
-through these helpers so a single module carries the version split.
+Meshes are built with explicit ``Auto`` axis types and entered with
+``jax.set_mesh``; keeping both here gives the code base one place that
+names the mesh API.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-try:  # jax >= 0.5: explicit axis types on meshes
-    from jax.sharding import AxisType
-
-    HAS_AXIS_TYPES = True
-except ImportError:  # jax 0.4.x
-    AxisType = None
-    HAS_AXIS_TYPES = False
-
-
-def auto_axis_types(n: int):
-    """``axis_types`` tuple for n Auto axes, or None when unsupported."""
-    if HAS_AXIS_TYPES:
-        return (AxisType.Auto,) * n
-    return None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
+    """``jax.make_mesh`` with every axis typed ``Auto``."""
     shape, axes = tuple(shape), tuple(axes)
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if HAS_AXIS_TYPES:
-        kwargs["axis_types"] = auto_axis_types(len(axes))
-    return jax.make_mesh(shape, axes, **kwargs)
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
-@contextlib.contextmanager
-def use_mesh(mesh):
-    """``jax.set_mesh`` when available, else the legacy Mesh context manager."""
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+use_mesh = jax.set_mesh

@@ -16,7 +16,7 @@ Public surface:
   TenantLedger / TenantLedgers            — per-tenant edge-read token buckets
   edgemap_round_read_words                — one dense round's read quantum
 """
-from .backend import GraphBackend, GraphLike, dense_block_view, tile_block_view
+from .backend import GraphBackend, GraphLike, dense_block_view, tile_block_reader
 from .bucketing import NULL_BUCKET, Buckets, make_buckets
 from .compressed import (
     CompressedCSR,
@@ -82,7 +82,7 @@ __all__ = [
     "decode_block",
     "decode_block_tile",
     "dense_block_view",
-    "tile_block_view",
+    "tile_block_reader",
     "edgemap_sum_compressed",
     "CSRGraph",
     "build_csr",

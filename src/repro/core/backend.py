@@ -22,14 +22,14 @@ The two structural hooks that differ per backend live here:
   dense (pull) pass.  For the compressed backend this is the lazy cumsum
   decode, which XLA fuses into the consuming gather/segment-reduce; the
   Pallas ``compressed_spmv`` kernel is the explicitly streamed variant.
-* ``tile_block_view``   — a C-block tile for the chunked (sparse) pass.
+* ``tile_block_reader`` — reads C-block tiles for the chunked (sparse) pass.
   For the compressed backend this decodes *inside the chunk loop*
   (App. D.1's "decode the whole block to fetch one edge" discipline), so
   peak intermediates stay ``chunk_blocks × F_B`` words for both formats.
 """
 from __future__ import annotations
 
-from typing import Protocol, Union, runtime_checkable
+from typing import Callable, Protocol, Union, runtime_checkable
 
 import jax.numpy as jnp
 
@@ -76,29 +76,43 @@ def dense_block_view(g: GraphBackend) -> tuple[jnp.ndarray, jnp.ndarray]:
     return g.block_dst, g.block_w
 
 
-def tile_block_view(
-    g: GraphBackend, bids: jnp.ndarray
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(dst, w), both (C, F_B), for a tile of block ids.
+def tile_block_reader(
+    g: GraphBackend,
+) -> Callable[[jnp.ndarray], tuple[jnp.ndarray, jnp.ndarray]]:
+    """``bids -> (dst, w)``, both (C, F_B), for a tile of block ids.
 
     ``bids`` rows equal to ``num_blocks`` (the compact_mask fill) yield
     all-sentinel targets / zero weights for both backends.  Anything that
     satisfies ``GraphBackend`` without being a ``CompressedCSR`` takes the
-    generic block-gather path.
+    generic block-gather path.  Build the reader outside a chunk loop: an
+    exception-dense ``CompressedCSR`` is decoded here, once, because XLA on
+    TPU does not hoist that whole-graph decode out of a ``while_loop`` and
+    would redo it for every chunk.
     """
     if isinstance(g, CompressedCSR):
         if exception_dense(g):
-            # COO patching would cost O(C·NE) per chunk; decode exactly
-            # instead — loop-invariant, so XLA hoists it out of the chunk
-            # loop and the tile is a plain row gather
-            dst = jnp.take(decode_blocks(g), bids, axis=0, mode="fill", fill_value=g.n)
+            # COO patching would cost O(C·NE) per chunk: decode exactly,
+            # once, and gather rows per tile
+            decoded = decode_blocks(g)
+            decode = lambda bids: jnp.take(  # noqa: E731
+                decoded, bids, axis=0, mode="fill", fill_value=g.n
+            )
         else:
-            dst = decode_block_tile(g, bids)
-        if g.block_weights is not None:
-            w = jnp.take(g.block_weights, bids, axis=0, mode="fill", fill_value=0.0)
-        else:
-            w = jnp.ones(dst.shape, jnp.float32)
+            decode = lambda bids: decode_block_tile(g, bids)  # noqa: E731
+
+        def read(bids):
+            dst = decode(bids)
+            if g.block_weights is not None:
+                w = jnp.take(g.block_weights, bids, axis=0, mode="fill", fill_value=0.0)
+            else:
+                w = jnp.ones(dst.shape, jnp.float32)
+            return dst, w
+
+        return read
+
+    def read(bids):
+        dst = jnp.take(g.block_dst, bids, axis=0, mode="fill", fill_value=g.n)
+        w = jnp.take(g.block_w, bids, axis=0, mode="fill", fill_value=0.0)
         return dst, w
-    dst = jnp.take(g.block_dst, bids, axis=0, mode="fill", fill_value=g.n)
-    w = jnp.take(g.block_w, bids, axis=0, mode="fill", fill_value=0.0)
-    return dst, w
+
+    return read

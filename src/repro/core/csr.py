@@ -167,15 +167,13 @@ def build_csr(
             np.concatenate([dst, src]),
             np.concatenate([w, w]),
         )
-    # drop self loops, dedupe
+    # drop self loops, dedupe; np.unique returns the keys sorted, so the
+    # edges come out ordered by (src, dst)
     keep = src != dst
     src, dst, w = src[keep], dst[keep], w[keep]
     key = src * n + dst
     _, uniq = np.unique(key, return_index=True)
     src, dst, w = src[uniq], dst[uniq], w[uniq]
-
-    order = np.lexsort((dst, src))
-    src, dst, w = src[order], dst[order], w[order]
     m = int(src.shape[0])
 
     deg = np.bincount(src, minlength=n).astype(np.int64)

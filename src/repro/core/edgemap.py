@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..tuning.defaults import DEFAULT_CHUNK_BLOCKS, DEFAULT_DENSE_FRAC
-from .backend import GraphLike, dense_block_view, tile_block_view
+from .backend import GraphLike, dense_block_view, tile_block_reader
 from .graph_filter import GraphFilter, edge_active_words, unpack_word_bits
 from .primitives import compact_mask, monoid_identity, segment_reduce
 from .vertex_subset import VertexSubset
@@ -228,6 +228,7 @@ def edgemap_chunked(
 
     stream_tile = _streaming_decoder(g, edge_active, interpret) if streamed else None
     bits = _edge_active_view(g, edge_active) if stream_tile is None else None
+    read_tile = tile_block_reader(g) if stream_tile is None else None
 
     def body(state):
         i, out, touched = state
@@ -240,7 +241,7 @@ def edgemap_chunked(
         else:
             # per-backend tile view; compressed backends decode here, inside
             # the chunk loop, so the peak intermediate stays C × F_B words
-            dsts, ws = tile_block_view(g, bids)            # (C, FB)
+            dsts, ws = read_tile(bids)                     # (C, FB)
             act = dsts < n
             if bits is not None:
                 act = act & _gather_rows(bits, bids, False)

@@ -50,7 +50,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -319,7 +318,7 @@ class ExecutionPlan:
                   runs with: False = native Mosaic, True = interpret mode,
                   None = defer to the per-backend default at the call site.
                   ``make_plan`` resolves it from the ``lowering`` knob
-                  (explicit arg → calibrated table → DEFAULT_LOWERING) and
+                  (explicit arg, else DEFAULT_LOWERING) and
                   folds it into ``tuning_key`` so the serving executable
                   cache never aliases lowerings
     pipeline_rounds — sharded round loops run software-pipelined: the O(n)
@@ -510,9 +509,15 @@ class ExecutionPlan:
             if self.is_sharded
             else "single-device"
         )
+        d = self.decisions
+        extra = (
+            ""
+            if d is None
+            else f" lowering={d.lowering} source={d.source} streamed={d.streamed}"
+        )
         return (
             f"plan[{where} backend={self.backend} strategy={self.strategy} "
-            f"reduce={self.reduce_mode} shards={self.num_shards}]"
+            f"reduce={self.reduce_mode} shards={self.num_shards}{extra}]"
         )
 
 
@@ -524,8 +529,11 @@ def _resolve_decision(backend: str, strategy: str, tuning):
     plans only — fixed-strategy plans keep the documented constants unless
     a table is passed explicitly), or ``None``/``"off"`` (static constants).
     Backends the table has no measurements for — including ``"auto"`` when
-    no graph was passed — fall back to the constants decision.
+    no graph was passed — fall back to the constants decision.  So does a
+    shipped table measured on another platform or device kind; that
+    decision's ``source`` is ``"host-mismatch"``.
     """
+    from ..tuning.measure import host_fingerprint
     from ..tuning.table import TuningTable, constants_decision, default_table
 
     if tuning is None or tuning == "off":
@@ -535,9 +543,16 @@ def _resolve_decision(backend: str, strategy: str, tuning):
     if tuning == "default":
         if strategy == "auto":
             try:
-                return default_table().decide(backend, strategy)
+                table = default_table()
             except (OSError, ValueError):  # missing/stale shipped table
                 return constants_decision(backend, strategy)
+            if not table.matches_host(host_fingerprint()):
+                return dataclasses.replace(
+                    constants_decision(backend, strategy),
+                    source="host-mismatch",
+                    table_host=table.host_key,
+                )
+            return table.decide(backend, strategy)
         return constants_decision(backend, strategy)
     raise ValueError(
         f"tuning must be a TuningTable, 'default', 'off' or None; got {tuning!r}"
@@ -573,8 +588,7 @@ def make_plan(
     ``lowering`` picks how every Pallas kernel under the plan lowers:
     ``"native"`` (Mosaic), ``"interpret"`` (XLA interpret mode), or
     ``"auto"`` (per-backend default — native where supported).  ``None``
-    defers to the tuning decision's calibrated winner, then to
-    ``repro.tuning.defaults.DEFAULT_LOWERING``.  The resolved value lands
+    defers to ``repro.tuning.defaults.DEFAULT_LOWERING``.  The resolved value lands
     on ``plan.decisions.lowering`` and in ``plan.tuning_key``.
 
     ``pipeline_rounds=True`` opts sharded round loops into the
@@ -618,6 +632,19 @@ def make_plan(
     resolved_lowering = resolve_lowering(
         lowering if lowering is not None else decision.lowering
     )
+    streams = strategy == "sparse_streamed" or (
+        strategy == "auto"
+        and "sparse_streamed"
+        in (decision.auto_sparse, decision.auto_sparse_batched)
+    )
+    streamed = None
+    if streams:
+        if isinstance(g, CompressedCSR):
+            streamed = (
+                "fallback:exception_dense" if exception_dense(g) else "kernel"
+            )
+        elif g is not None:
+            streamed = "fallback:no_decoder"
     decision = dataclasses.replace(
         decision,
         strategy=strategy,
@@ -625,6 +652,7 @@ def make_plan(
         dense_frac_batched=dense_frac_batched,
         chunk_blocks=int(chunk_blocks),
         lowering=resolved_lowering,
+        streamed=streamed,
     )
     return ExecutionPlan(
         mesh=mesh,
@@ -813,14 +841,14 @@ def _sharded_edgemap_call(
     if has_lanes:
         in_specs.append(P())
         operands.append(map_lanes)
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=plan.mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(), P()),
         # the hierarchical all_gather(psum_scatter(...)) is replicated over
         # the fast axis but the static replication check can't prove it
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(*operands)
     reg = get_registry()
@@ -1024,7 +1052,9 @@ def round_loop(
     before the sweep (wBFS settles its extracted bucket); ``epilogue(state,
     out, touched) -> state`` applies the combined sweep; ``cond_fn(state)``
     is the loop predicate.  All three must be collective-free — the driver
-    owns every cross-shard word.
+    owns every cross-shard word.  On the pipelined path they run inside
+    ``shard_map``, which refuses arrays they capture from a mesh context:
+    such arrays ride in ``state`` instead (see ``pagerank``).
     """
     pipelined = (
         plan is not None and plan.is_sharded and plan.pipeline_rounds
@@ -1089,24 +1119,34 @@ def round_loop(
 
     local_reduce = edgemap_reduce_batched if batched else edgemap_reduce
 
-    def whole(sg, st0, *rest):
+    def local_sweep(sg, frontier, x, *rest):
+        # local (uncombined) sweep — same body the sequential sharded
+        # executor runs per shard, resolved from the same plan knobs
         g_local = jax.tree.map(lambda a: a[0], sg.shards)
         kwargs = {} if map_fn is None else {"map_fn": map_fn}
         if batched and flavor_crossover is not None:
             kwargs["flavor_crossover"] = flavor_crossover
         if has_active:
             kwargs["edge_active"] = rest[0].words[0]
-        out_dtype = jax.eval_shape(lambda s: sweep_inputs(s)[2], st0).dtype
+        with jax.named_scope("sage.round.sweep"):
+            return local_reduce(
+                g_local, frontier, x, monoid=monoid, mode=rmode,
+                dense_frac=dense_frac, chunk_blocks=chunk_blocks,
+                auto_sparse=auto_sparse, interpret=interpret, **kwargs,
+            )
 
+    # shape probes run outside the shard_map body: under its manual mesh,
+    # eval_shape would re-trace the callbacks against the auto mesh
+    rest_ops = [active] if has_active else []
+    out_dtype = jax.eval_shape(lambda s: sweep_inputs(s)[2], state).dtype
+    pending_shapes = jax.eval_shape(
+        lambda sg, s, *rest: local_sweep(sg, *sweep_inputs(s)[1:], *rest),
+        g, state, *rest_ops,
+    )
+
+    def whole(sg, st0, *rest):
         def sweep(frontier, x):
-            # local (uncombined) sweep — same body the sequential sharded
-            # executor runs per shard, resolved from the same plan knobs
-            with jax.named_scope("sage.round.sweep"):
-                return local_reduce(
-                    g_local, frontier, x, monoid=monoid, mode=rmode,
-                    dense_frac=dense_frac, chunk_blocks=chunk_blocks,
-                    auto_sparse=auto_sparse, interpret=interpret, **kwargs,
-                )
+            return local_sweep(sg, frontier, x, *rest)
 
         def combine(pending):
             out, touched = pending
@@ -1124,8 +1164,9 @@ def round_loop(
 
             return lax.cond(flag, do, dont, st, pending)
 
-        shapes = jax.eval_shape(lambda s: sweep(*sweep_inputs(s)[1:]), st0)
-        zeros = jax.tree.map(lambda sd: jnp.zeros(sd.shape, sd.dtype), shapes)
+        zeros = jax.tree.map(
+            lambda sd: jnp.zeros(sd.shape, sd.dtype), pending_shapes
+        )
 
         flag0 = cond_fn(st0)
         st1, pending0 = maybe_sweep(st0, zeros, flag0)
@@ -1148,14 +1189,14 @@ def round_loop(
     if has_active:
         in_specs.append(P(plan.axes))
         operands.append(active)
-    fn = shard_map(
+    fn = jax.shard_map(
         whole,
         mesh=plan.mesh,
         in_specs=tuple(in_specs),
         out_specs=P(),
         # every shard computes the same replicated state (the combine is
         # replicated by construction) but the static check can't prove it
-        check_rep=False,
+        check_vma=False,
     )
     final = fn(*operands)
     return final if observe is None else observe(final, "pipelined")

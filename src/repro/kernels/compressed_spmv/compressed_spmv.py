@@ -5,7 +5,7 @@ PSAM → TPU mapping: the vertex state ``x`` (small memory) is VMEM-resident
 across the whole grid; the *compressed* edge blocks — one int32 first-target
 plus uint16 deltas per block — are streamed HBM→VMEM at roughly half the
 bytes of the int32 target stream the uncompressed kernel reads.  The decode
-(a lane-dimension cumsum) happens in VMEM, fused with the gather and the
+(a lane-dimension prefix sum) happens in VMEM, fused with the gather and the
 masked reduction: the int32 targets are never materialized in HBM, which is
 the TPU analogue of the paper's "decode the whole block to fetch one edge"
 filter-iterator discipline.  The graphFilter bits ride along as one uint32
@@ -54,12 +54,42 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.graph_filter import unpack_word_bits
 from ...tuning.defaults import DEFAULT_TILE_BLOCKS  # TB: edge-blocks per program
 from ..lowering import resolve_interpret
+
+
+def _decode_tile(first, deltas, vc):
+    """Fused block decode of one tile, in ops Mosaic lowers.
+
+    ``first`` and ``vc`` are (TB, 1) int32 columns (first target, valid
+    slot count), ``deltas`` the (TB, FB) uint16 tile.  Returns the decoded
+    int32 targets, the lane iota and the structural padding mask.  The lane
+    prefix sum is log2(FB) shifted adds (``pltpu.roll``) in int32, so it is
+    exact and equal to ``jnp.cumsum``, which Mosaic cannot lower.
+    """
+    d = deltas.astype(jnp.int32)
+    lane = lax.broadcasted_iota(jnp.int32, d.shape, 1)
+    d = jnp.where(lane == 0, 0, d)  # lane 0's delta slot is unused
+    shift = 1
+    while shift < d.shape[1]:
+        d = d + jnp.where(lane >= shift, pltpu.roll(d, shift, 1), 0)
+        shift *= 2
+    return first + d, lane, lane < vc
+
+
+def _lane_bits(words, lane):
+    """(TB, FB/32) packed uint32 words → (TB, FB) bool, in the canonical
+    little-endian order of ``graph_filter.unpack_word_bits``.  Each lane
+    selects its word by comparison instead of the 3-D reshape Mosaic
+    refuses."""
+    word = jnp.zeros(lane.shape, jnp.uint32)
+    for w in range(words.shape[1]):
+        word = jnp.where(lane // 32 == w, words[:, w : w + 1], word)
+    return ((word >> (lane & 31).astype(jnp.uint32)) & jnp.uint32(1)) != 0
 
 
 def _kernel(
@@ -78,25 +108,14 @@ def _kernel(
     out_ref = refs.pop()
     act_ref = refs.pop(0) if has_active else None  # rides right after bits
     w_ref = refs.pop(0) if has_weights else None
-    first = first_ref[...]        # (TB,)   int32 — first target per block
-    deltas = deltas_ref[...]      # (TB, FB) uint16 — streamed compressed tile
-    vc = vc_ref[...]              # (TB,)   int32 — valid (front-packed) slots
     x = x_ref[...]                # (n_pad,) or (B, n_pad) — PSAM small memory
-    bits = bits_ref[...]          # (TB, FB//32) uint32 — graphFilter view
-
-    # fused decode: zero the unused lane-0 delta, cumsum along lanes.
-    # Decoded ONCE per tile regardless of the query-batch width.
-    d = deltas.astype(jnp.int32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
-    d = jnp.where(lane == 0, 0, d)
-    dst = first[:, None] + jnp.cumsum(d, axis=1)
-
-    act = unpack_word_bits(bits)  # (TB, FB) bool, canonical graphFilter order
+    # fused decode of the (TB, FB) uint16 tile against the (TB, 1) first
+    # targets / valid counts — decoded ONCE per tile whatever the batch width
+    dst, lane, mask = _decode_tile(first_ref[...], deltas_ref[...], vc_ref[...])
+    mask = mask & _lane_bits(bits_ref[...], lane)  # graphFilter view
     if act_ref is not None:
         # per-call traversal mask: same packed layout, ANDed in VMEM
-        act = act & unpack_word_bits(act_ref[...])
-
-    mask = (lane < vc[:, None]) & act  # structural padding mask ∧ filter bits
+        mask = mask & _lane_bits(act_ref[...], lane)
     safe = jnp.where(mask & (dst < jnp.int32(n)), dst, 0)
     if batched:
         # one compressed tile, B query columns: gather fans the decoded
@@ -153,16 +172,17 @@ def compressed_block_spmv_pallas(
     backend — native Mosaic on TPU, interpret mode elsewhere
     (:mod:`repro.kernels.lowering`).
     """
-    interpret = resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret, kernel="compressed_block_spmv")
     batched = x.ndim == 2
     NB, FB = deltas.shape
-    vc = valid_count.astype(jnp.int32)
+    block_first = block_first.reshape(NB, 1)
+    vc = valid_count.astype(jnp.int32).reshape(NB, 1)
     TB = min(tile_blocks, NB)
     pad = (-NB) % TB
     if pad:
-        block_first = jnp.pad(block_first, (0, pad), constant_values=n)
+        block_first = jnp.pad(block_first, ((0, pad), (0, 0)), constant_values=n)
         deltas = jnp.pad(deltas, ((0, pad), (0, 0)))
-        vc = jnp.pad(vc, (0, pad))
+        vc = jnp.pad(vc, ((0, pad), (0, 0)))
         bits = jnp.pad(bits, ((0, pad), (0, 0)))
         if edge_active is not None:
             edge_active = jnp.pad(edge_active, ((0, pad), (0, 0)))
@@ -179,9 +199,9 @@ def compressed_block_spmv_pallas(
     )
     in_specs = [
         x_spec,
-        pl.BlockSpec((TB,), lambda i: (i,)),          # compressed stream:
+        pl.BlockSpec((TB, 1), lambda i: (i, 0)),      # compressed stream:
         pl.BlockSpec((TB, FB), lambda i: (i, 0)),     #   first + deltas
-        pl.BlockSpec((TB,), lambda i: (i,)),          #   + valid counts
+        pl.BlockSpec((TB, 1), lambda i: (i, 0)),      #   + valid counts
         pl.BlockSpec((TB, W), lambda i: (i, 0)),
     ]
     operands = [x, block_first, deltas, vc, bits]
@@ -241,26 +261,18 @@ def _chunked_kernel(
     act_ref = refs.pop(0) if has_active else None
     w_ref = refs.pop(0) if has_weights else None
 
-    first = first_ref[...]        # (1,)    int32
-    deltas = deltas_ref[...]      # (1, FB) uint16 — ONE live block's tile
-    vc = vc_ref[...]              # (1,)    int32
-
-    d = deltas.astype(jnp.int32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
-    d = jnp.where(lane == 0, 0, d)
-    dst = first[:, None] + jnp.cumsum(d, axis=1)
-
-    mask = lane < vc[:, None]
+    # (rows, 1) first targets / valid counts, (rows, FB) uint16 deltas
+    dst, lane, mask = _decode_tile(first_ref[...], deltas_ref[...], vc_ref[...])
     if bits_ref is not None:
-        mask = mask & unpack_word_bits(bits_ref[...])
+        mask = mask & _lane_bits(bits_ref[...], lane)
     if act_ref is not None:
-        mask = mask & unpack_word_bits(act_ref[...])
+        mask = mask & _lane_bits(act_ref[...], lane)
 
     if emit == "decode":
         dst_out_ref, w_out_ref = refs
         dst_out_ref[...] = jnp.where(mask & (dst < jnp.int32(n)), dst, jnp.int32(n))
         w_out_ref[...] = (
-            w_ref[...] if w_ref is not None else jnp.ones(deltas.shape, jnp.float32)
+            w_ref[...] if w_ref is not None else jnp.ones(dst.shape, jnp.float32)
         )
         return
 
@@ -347,25 +359,32 @@ def compressed_chunked_spmv_pallas(
     """
     if emit not in ("sums", "decode"):
         raise ValueError(f"emit must be 'sums' or 'decode', got {emit!r}")
-    interpret = resolve_interpret(interpret)
+    interpret = resolve_interpret(
+        interpret,
+        kernel=(
+            "compressed_chunked_decode"
+            if emit == "decode" and gather_tiles
+            else f"compressed_chunked_{emit}" + ("" if gather_tiles else "_rows")
+        ),
+    )
     NB, FB = deltas.shape
     C = ids.shape[0]
     W = FB // 32
     batched = emit == "sums" and x.ndim == 2
 
-    # the all-sentinel row: out-of-range ids (chunk pad) land here and
-    # decode to nothing (valid_count 0; first target = n for belt-and-braces)
-    first_s = jnp.pad(block_first, (0, 1), constant_values=n)
-    deltas_s = jnp.pad(deltas, ((0, 1), (0, 0)))
-    vc_s = jnp.pad(valid_count.astype(jnp.int32), (0, 1))
     ids = jnp.minimum(ids.astype(jnp.int32), jnp.int32(NB))
-
     if gather_tiles:
         return _chunked_tiled_call(
-            x, ids, first_s, deltas_s, vc_s, bits, edge_active, block_weights,
-            n=n, emit=emit, batched=batched, C=C, NB=NB, FB=FB, W=W,
-            tile_blocks=tile_blocks, interpret=interpret,
+            x, ids, block_first, deltas, valid_count, bits, edge_active,
+            block_weights, n=n, emit=emit, batched=batched, C=C, NB=NB, FB=FB,
+            W=W, tile_blocks=tile_blocks, interpret=interpret,
         )
+
+    # the all-sentinel row: out-of-range ids (chunk pad) land here and
+    # decode to nothing (valid_count 0; first target = n for belt-and-braces)
+    first_s = jnp.pad(block_first, (0, 1), constant_values=n).reshape(NB + 1, 1)
+    deltas_s = jnp.pad(deltas, ((0, 1), (0, 0)))
+    vc_s = jnp.pad(valid_count.astype(jnp.int32), (0, 1)).reshape(NB + 1, 1)
 
     in_specs = []
     operands = []
@@ -377,9 +396,9 @@ def compressed_chunked_spmv_pallas(
         )
         operands.append(x)
     in_specs += [
-        pl.BlockSpec((1,), lambda i, ids: (ids[i],)),       # first targets
+        pl.BlockSpec((1, 1), lambda i, ids: (ids[i], 0)),   # first targets
         pl.BlockSpec((1, FB), lambda i, ids: (ids[i], 0)),  # delta stream
-        pl.BlockSpec((1,), lambda i, ids: (ids[i],)),       # valid counts
+        pl.BlockSpec((1, 1), lambda i, ids: (ids[i], 0)),   # valid counts
     ]
     operands += [first_s, deltas_s, vc_s]
     if bits is not None:
@@ -432,15 +451,17 @@ def compressed_chunked_spmv_pallas(
 
 
 def _chunked_tiled_call(
-    x, ids, first_s, deltas_s, vc_s, bits, edge_active, block_weights,
+    x, ids, block_first, deltas, valid_count, bits, edge_active, block_weights,
     *, n, emit, batched, C, NB, FB, W, tile_blocks, interpret,
 ):
     """The ``gather_tiles`` grid: pre-gathered live rows, (TB, FB) tiles.
 
-    ``ids`` is already clamped onto the all-sentinel row (index NB), so the
-    pad extending C to a TB multiple just names more sentinel rows — they
-    decode to nothing.  The gather reads exactly the live rows (+ sentinel)
-    out of the compressed arrays; the kernel then walks contiguous (TB, FB)
+    ``ids`` is already clamped to NB, the id of the all-sentinel row, which
+    the gather fills in (valid count 0, first target ``n``) rather than
+    copying a padded compressed array; the pad extending C to a TB multiple
+    names more such rows, and they decode to nothing.  The gather reads
+    exactly the live rows out of the compressed arrays; the kernel then
+    walks contiguous (TB, FB)
     tiles, so every HBM→VMEM transfer is DMA-sized and the grid pipeline
     overlaps tile i+1's fetch with tile i's decode.
     """
@@ -450,9 +471,13 @@ def _chunked_tiled_call(
         ids = jnp.pad(ids, (0, pad), constant_values=NB)
     c_pad = C + pad
 
-    first_g = jnp.take(first_s, ids)               # (C_pad,)
-    deltas_g = jnp.take(deltas_s, ids, axis=0)     # (C_pad, FB) — live rows only
-    vc_g = jnp.take(vc_s, ids)
+    def rows(a, fill):
+        # live rows only; ids == NB fill as the all-sentinel row
+        return jnp.take(a, ids, axis=0, mode="fill", fill_value=fill)
+
+    first_g = rows(block_first, n).reshape(c_pad, 1)
+    deltas_g = rows(deltas, 0)                             # (C_pad, FB)
+    vc_g = rows(valid_count.astype(jnp.int32), 0).reshape(c_pad, 1)
 
     in_specs = []
     operands = []
@@ -464,24 +489,20 @@ def _chunked_tiled_call(
         )
         operands.append(x)
     in_specs += [
-        pl.BlockSpec((TB,), lambda i: (i,)),
+        pl.BlockSpec((TB, 1), lambda i: (i, 0)),
         pl.BlockSpec((TB, FB), lambda i: (i, 0)),
-        pl.BlockSpec((TB,), lambda i: (i,)),
+        pl.BlockSpec((TB, 1), lambda i: (i, 0)),
     ]
     operands += [first_g, deltas_g, vc_g]
     if bits is not None:
         in_specs.append(pl.BlockSpec((TB, W), lambda i: (i, 0)))
-        operands.append(jnp.take(jnp.pad(bits, ((0, 1), (0, 0))), ids, axis=0))
+        operands.append(rows(bits, 0))
     if edge_active is not None:
         in_specs.append(pl.BlockSpec((TB, W), lambda i: (i, 0)))
-        operands.append(
-            jnp.take(jnp.pad(edge_active, ((0, 1), (0, 0))), ids, axis=0)
-        )
+        operands.append(rows(edge_active, 0))
     if block_weights is not None:
         in_specs.append(pl.BlockSpec((TB, FB), lambda i: (i, 0)))
-        operands.append(
-            jnp.take(jnp.pad(block_weights, ((0, 1), (0, 0))), ids, axis=0)
-        )
+        operands.append(rows(block_weights, 0.0))
 
     if emit == "decode":
         out_specs = (

@@ -221,8 +221,8 @@ def compressed_chunked_stream_tile(
     cumsum decode and the packed ``edge_active`` masking in-VMEM; ESCAPE
     blocks are then recomputed exactly and patched keyed on the gathered
     ids.  This is the tile view the core ``edgemap_chunked`` streamed path
-    consumes in place of ``tile_block_view`` — same contract, but the dead
-    blocks' compressed bytes are never read.
+    consumes in place of ``tile_block_reader``'s tiles — same contract, but
+    the dead blocks' compressed bytes are never read.
 
     ``exact_rows``: optionally the precomputed
     ``_exception_row_targets(c, words)`` — it is id-independent, so a

@@ -72,7 +72,7 @@ def decode_attention_pallas(
     seq_tile: int = DEFAULT_SEQ_TILE,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    interpret = resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret, kernel="decode_attention")
     B, H, D = q.shape
     S = k.shape[1]
     st = min(seq_tile, S)

@@ -91,7 +91,7 @@ def edge_block_spmv_pallas(
 
     ``interpret=None`` (the default) resolves the lowering per backend —
     native Mosaic on TPU, interpret mode elsewhere."""
-    interpret = resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret, kernel="edge_block_spmv")
     batched = x.ndim == 2
     NB, FB = block_dst.shape
     TB = min(tile_blocks, NB)

@@ -44,7 +44,7 @@ def embedding_bag_pallas(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Returns (B, D) weighted bag sums."""
-    interpret = resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret, kernel="embedding_bag")
     V, D = table.shape
     B, L = indices.shape
     TB = min(tile_batch, B)

@@ -58,7 +58,7 @@ def filter_pack_pallas(
     interpret: bool | None = None,
 ):
     """Returns (new_bits (NB, W) uint32, active_count (NB,) int32)."""
-    interpret = resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret, kernel="filter_pack")
     NB, W = bits.shape
     FB = keep.shape[1]
     TB = min(tile_blocks, NB)

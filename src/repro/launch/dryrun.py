@@ -48,12 +48,8 @@ _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 
 
 def cost_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized across jax versions (some
-    return the per-computation dict, 0.4.x returns a one-element list)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``Compiled.cost_analysis()``, or ``{}`` where the backend has none."""
+    return compiled.cost_analysis() or {}
 
 
 def _shape_bytes(shape_str: str) -> int:

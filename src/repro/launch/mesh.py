@@ -4,8 +4,8 @@ A FUNCTION, not a module-level constant: importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before any jax import; smoke
 tests see the real single CPU device).
 
-Mesh construction goes through ``repro.compat`` so the same code runs on
-JAX versions with and without ``jax.sharding.AxisType``.
+Mesh construction goes through ``repro.compat``, the one module that
+names the mesh API.
 """
 from __future__ import annotations
 

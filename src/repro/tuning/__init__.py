@@ -14,8 +14,8 @@ replaces assumption with measurement:
   default_table            — the shipped offline table (cold-start path)
   load_table               — load a calibrated table (or the default)
   constants_decision       — the static-defaults decision (un-tuned plans)
-  hardware_model           — the one hardware description (peak FLOPs,
-                             HBM/ICI bandwidth) roofline + calibration share
+  hardware_model           — published per-chip peaks (FLOP/s, HBM and
+                             ICI bandwidth) keyed by device kind
   crossover_from_sweep     — density where dense becomes cheaper, from
                              measured sweep rows
   dense_frac_from_crossover— Beamer threshold equivalent of a crossover
@@ -31,7 +31,7 @@ replaces assumption with measurement:
 
 plus the static defaults (``DEFAULT_DENSE_FRAC``, ``DEFAULT_CHUNK_BLOCKS``,
 ``DEFAULT_TILE_BLOCKS``, ``DEFAULT_MAX_BATCH``, ``DEFAULT_EST_ROUNDS``,
-``DEFAULT_LOWERING``, ``DEFAULT_HARDWARE``) — module-level constants documented in
+``DEFAULT_LOWERING``, ``HARDWARE_PEAKS``) — module-level constants documented in
 ``repro.tuning.defaults``.
 
 CLI: ``python -m repro.tuning --quick --out table.json`` (the nightly job).
@@ -45,10 +45,10 @@ from .defaults import (
     DEFAULT_CHUNK_BLOCKS,
     DEFAULT_DENSE_FRAC,
     DEFAULT_EST_ROUNDS,
-    DEFAULT_HARDWARE,
     DEFAULT_LOWERING,
     DEFAULT_MAX_BATCH,
     DEFAULT_TILE_BLOCKS,
+    HARDWARE_PEAKS,
 )
 from .measure import calibrate, host_fingerprint
 from .overlay import (
@@ -77,7 +77,7 @@ __all__ = [
     "DEFAULT_MAX_BATCH",
     "DEFAULT_EST_ROUNDS",
     "DEFAULT_LOWERING",
-    "DEFAULT_HARDWARE",
+    "HARDWARE_PEAKS",
     "OverlayTrigger",
     "TuningTable",
     "TuningDecision",

@@ -35,10 +35,10 @@ never needs to know whether the value was hand-picked or calibrated:
   resolves per backend at plan time (native Mosaic on TPU, XLA interpret
   mode elsewhere); ``"native"`` / ``"interpret"`` force one side.  A
   calibrated table replaces ``"auto"`` with the measured winner.
-* ``DEFAULT_HARDWARE``     — the analytic hardware model (TPU v5e-class):
-  peak bf16 FLOP/s, HBM bandwidth, effective per-link ICI bandwidth.  The
-  roofline benchmark and the calibration pass both read THIS description,
-  so there is one set of hardware constants, not two divergent ones.
+* ``HARDWARE_PEAKS``       — published per-chip peaks keyed by
+  ``jax.Device.device_kind``: bf16 FLOP/s, HBM bandwidth and capacity,
+  per-link ICI bandwidth.  ``repro.tuning.hardware_model`` is the one
+  reader; a device kind missing here is an error, never a default.
 
 This module is import-light on purpose (no jax, no numpy): ``repro.core``
 imports it at module load, so it must never import back into core.
@@ -55,10 +55,14 @@ DEFAULT_COMPACT_HYSTERESIS = 1.0
 DEFAULT_OVERLAY_COST_SCALE = 1.0
 DEFAULT_EDITS_PER_COMPACT = 1024
 
-# TPU v5e-class per chip: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI
-# (one effective link per collective hop — conservative).
-DEFAULT_HARDWARE = {
-    "peak_flops": 197e12,
-    "hbm_bw": 819e9,
-    "ici_bw": 50e9,
+# Source: Google Cloud documentation, "TPU v5e" — per chip 197 TFLOP/s
+# bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+# interconnect (four 50 GB/s links; a collective hop is priced at one).
+HARDWARE_PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,
+        "hbm_bw": 819e9,
+        "hbm_bytes": 16e9,
+        "ici_bw": 50e9,
+    },
 }

@@ -22,10 +22,6 @@ use), both backends:
   runs one live-block loop shared by all lanes (its streamed/plain flip →
   ``batched_flavor_crossover``, the density where batched auto switches
   sparse flavor at runtime).
-* **Lowering sweep** — time one sparse edgeMap round per Pallas lowering
-  this host can run (``interpret`` always, ``native`` where Mosaic is
-  available); with both measured, the winner becomes the table's
-  ``lowering`` and ``make_plan`` pins it instead of the per-backend auto.
 * **Tile sweep** (compressed backend, full mode only) — time the Pallas
   ``compressed_spmv_vertex`` kernel across TB tile candidates.
 * **Shard sweep** (full mode, multi-device hosts only) — time a mesh plan
@@ -50,8 +46,6 @@ import time
 
 from .defaults import (
     DEFAULT_CHUNK_BLOCKS,
-    DEFAULT_HARDWARE,
-    DEFAULT_LOWERING,
     DEFAULT_MAX_BATCH,
     DEFAULT_TILE_BLOCKS,
 )
@@ -290,33 +284,6 @@ def _tile_sweep(g, grid, *, reps: int) -> list[dict]:
     return rows
 
 
-def _lowering_sweep(g, *, frac: float, seed: int, reps: int) -> list[dict]:
-    """Interpret vs native Pallas lowering of one sparse edgeMap round.
-
-    Only lowerings this process can actually run are timed — on hosts
-    without Mosaic support the sweep has a single ``interpret`` row and
-    the decision stays ``DEFAULT_LOWERING`` (auto)."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..core.edgemap import edgemap_reduce
-    from ..kernels.lowering import native_lowering_supported
-
-    mask = _frontier_for_fraction(g, frac, seed)
-    x = jnp.arange(g.n, dtype=jnp.int32)
-    cands = ["interpret"] + (["native"] if native_lowering_supported() else [])
-    rows = []
-    for low in cands:
-        fn = jax.jit(
-            lambda m, xv, low=low: edgemap_reduce(
-                g, m, xv, monoid="min", mode="sparse",
-                interpret=low == "interpret",
-            )
-        )
-        rows.append({"lowering": low, "us": _time_us(fn, mask, x, reps=reps)})
-    return rows
-
-
 def _knee(batch_sweep: list[dict], tol: float = 1.10) -> int:
     """Smallest B within ``tol`` of the best per-query amortization."""
     if not batch_sweep:
@@ -388,15 +355,6 @@ def _backend_entry(g, *, quick: bool, seed: int, reps: int, tile: bool) -> dict:
         "auto_sparse_batched": auto_sparse_batched,
         "batched_flavor_crossover": flavor_crossover,
     }
-    # Pallas lowering: record the measured winner only when both sides
-    # could run here; a single-candidate sweep keeps the portable default.
-    lowering_sweep = _lowering_sweep(g, frac=mid, seed=seed, reps=reps)
-    entry["lowering_sweep"] = lowering_sweep
-    entry["lowering"] = (
-        min(lowering_sweep, key=lambda r: r["us"])["lowering"]
-        if len(lowering_sweep) > 1
-        else DEFAULT_LOWERING
-    )
     if tile and _has_streaming(g):
         tile_sweep = _tile_sweep(g, _TILE_GRID, reps=reps)
         entry["tile_sweep"] = tile_sweep
@@ -458,7 +416,6 @@ def calibrate(
         "created": None,  # stamped by the CLI (host wall clock)
         "quick": bool(quick),
         "host": host_fingerprint(),
-        "hardware": dict(DEFAULT_HARDWARE),
         "graph": {"n": int(g.n), "m": int(g.m), "block_size": int(block_size)},
         "backends": {
             "csr": _backend_entry(g, quick=quick, seed=seed, reps=reps, tile=False),

@@ -16,7 +16,6 @@ Schema (JSON, ``schema_version`` checked strictly on load):
 
     schema_version : int           — must equal SCHEMA_VERSION
     host           : {platform, device_kind, device_count, machine, python}
-    hardware       : {peak_flops, hbm_bw, ici_bw}   — shared with roofline
     graph          : {n, m, block_size}             — calibration workload
     backends       : {backend name → backend entry}
 
@@ -55,15 +54,15 @@ from functools import lru_cache
 from .defaults import (
     DEFAULT_CHUNK_BLOCKS,
     DEFAULT_DENSE_FRAC,
-    DEFAULT_HARDWARE,
     DEFAULT_LOWERING,
     DEFAULT_MAX_BATCH,
     DEFAULT_TILE_BLOCKS,
+    HARDWARE_PEAKS,
 )
 
 SCHEMA_VERSION = 1
 
-_REQUIRED_TOP = ("schema_version", "host", "hardware", "backends")
+_REQUIRED_TOP = ("schema_version", "host", "backends")
 _REQUIRED_BACKEND = (
     "density_sweep",
     "crossover_density",
@@ -84,10 +83,21 @@ class TuningDecision:
     thresholds a run used and where they came from: ``source`` is
     ``"measured"`` (a TuningTable supplied them), or ``"constants"`` (the
     static defaults in ``repro.tuning.defaults``); explicit keyword
-    overrides are folded in either way.  ``crossover_density`` is the
+    overrides are folded in either way.  A third value,
+    ``"host-mismatch"``, marks constants chosen because the shipped table
+    was measured on another platform or device kind: its crossovers do not
+    transfer.  ``crossover_density`` is the
     measured dense/sparse wall-time crossover the ``dense_frac`` threshold
     was derived from (``dense_frac = 1 / crossover``); ``table_host`` /
-    ``table_version`` identify the table behind a measured decision.
+    ``table_version`` identify the table behind a measured (or rejected)
+    decision.
+
+    ``streamed`` says what a ``sparse_streamed`` round of this plan runs on
+    the graph it was planned for: ``"kernel"`` (the streamed-decode Pallas
+    kernel), ``"fallback:exception_dense"`` (too many ≥2¹⁶ deltas for the
+    kernel's exception patch, so plain ``sparse`` runs) or
+    ``"fallback:no_decoder"`` (a backend with no compressed stream).
+    ``None`` when no graph was given or no strategy of the plan streams.
     """
 
     source: str
@@ -105,6 +115,7 @@ class TuningDecision:
     crossover_density: float | None = None
     table_host: str | None = None
     table_version: int | None = None
+    streamed: str | None = None
 
 
 def crossover_from_sweep(sweep: list[dict]) -> float:
@@ -276,11 +287,12 @@ class TuningTable:
         h = self.host
         return f"{h.get('platform', '?')}/{h.get('device_kind', '?')}"
 
-    @property
-    def hardware(self) -> dict:
-        """The hardware model (peak_flops, hbm_bw, ici_bw) — the one
-        description shared by roofline terms and calibration."""
-        return self._data["hardware"]
+    def matches_host(self, host: dict) -> bool:
+        """True when ``host`` (a ``host_fingerprint``) has this table's
+        platform and device kind — the two things its timings depend on."""
+        return all(
+            self.host.get(k) == host.get(k) for k in ("platform", "device_kind")
+        )
 
     @property
     def schema_version(self) -> int:
@@ -317,16 +329,6 @@ class TuningTable:
         if e and e.get("tile_blocks"):
             return int(e["tile_blocks"])
         return DEFAULT_TILE_BLOCKS
-
-    def lowering(self, backend: str) -> str:
-        """The measured Pallas lowering winner for this backend's kernels:
-        ``"native"`` or ``"interpret"`` when the calibration sweep timed
-        both and one won, else ``DEFAULT_LOWERING`` (``"auto"`` — resolved
-        per process backend at plan time)."""
-        e = self._entry(backend)
-        if e and e.get("lowering"):
-            return str(e["lowering"])
-        return DEFAULT_LOWERING
 
     def auto_sparse(self, backend: str) -> str:
         """Which sparse flavor measured cheaper for this backend:
@@ -428,7 +430,6 @@ class TuningTable:
             dense_frac_batched=self.dense_frac_batched(backend),
             batched_flavor_crossover=self.batched_flavor_crossover(backend),
             tile_blocks=self.tile_blocks(backend),
-            lowering=self.lowering(backend),
             crossover_density=self.crossover_density(backend),
             table_host=self.host_key,
             table_version=self.schema_version,
@@ -467,14 +468,21 @@ def load_table(path: str | None = None) -> TuningTable:
     return default_table() if path is None else TuningTable.load(path)
 
 
-def hardware_model(table: TuningTable | None = None) -> dict:
-    """The hardware description (peak_flops, hbm_bw, ici_bw) benchmarks
-    should price against: the given (or shipped) table's ``hardware``
-    section, falling back to ``DEFAULT_HARDWARE`` when no table loads."""
+def hardware_model(device_kind: str | None = None) -> dict:
+    """Published peaks (peak_flops, hbm_bw, hbm_bytes, ici_bw) of one chip.
+
+    ``device_kind`` defaults to this process's first device.  A kind with
+    no entry in ``repro.tuning.defaults.HARDWARE_PEAKS`` raises KeyError:
+    a roofline priced against another chip's peaks is wrong, not
+    approximate."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
     try:
-        tbl = table or default_table()
-        hw = dict(DEFAULT_HARDWARE)
-        hw.update(tbl.hardware)
-        return hw
-    except (OSError, ValueError):
-        return dict(DEFAULT_HARDWARE)
+        return dict(HARDWARE_PEAKS[device_kind])
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(HARDWARE_PEAKS)}"
+        ) from None

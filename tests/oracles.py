@@ -39,7 +39,9 @@ def adj_sets(g):
 def bfs_levels(g, src):
     A = to_scipy(g)
     A.data[:] = 1.0
-    dist = csg.shortest_path(A, method="BF", unweighted=True, indices=src)
+    # hop counts by Dijkstra on unit weights: O(m log n), where
+    # Bellman-Ford's O(n m) cannot reach Graph500 scales
+    dist = csg.shortest_path(A, method="D", unweighted=True, indices=src)
     lev = np.where(np.isinf(dist), -1, dist).astype(np.int64)
     return lev
 

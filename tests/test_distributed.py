@@ -111,13 +111,12 @@ def test_compressed_psum_multidevice():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.compat import make_mesh, use_mesh
 from repro.optim import compressed_psum
 mesh = make_mesh((4,), ("pod",))
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 32))
-f = shard_map(lambda a: compressed_psum(a[0], "pod")[None],
+f = jax.shard_map(lambda a: compressed_psum(a[0], "pod")[None],
               mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))
 with use_mesh(mesh):
     got = f(x)

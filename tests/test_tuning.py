@@ -223,9 +223,11 @@ def test_default_table_ships_and_plans_measured():
         # the calibrated knobs reach the plan AND its cache-key summary
         assert plan.tuning_key[4] == plan.dense_frac
         assert plan.dense_frac == t.dense_frac(plan.backend)
-    # hardware model is the table's section over the defaults
-    hw = hardware_model()
+    # the hardware model is the published peaks of a known device kind
+    hw = hardware_model("TPU v5 lite")
     assert set(hw) >= {"peak_flops", "hbm_bw", "ici_bw"}
+    with pytest.raises(KeyError):
+        hardware_model("no such chip")
 
 
 def test_constants_decision_matches_defaults():
