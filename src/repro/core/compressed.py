@@ -15,9 +15,11 @@ graphFilter bits apply unchanged on top of the decoded block.
 ``CompressedCSR`` is a first-class execution backend: it exposes the same
 block view (``block_src`` / ``block_dst`` / ``block_w`` / ``edge_valid``)
 that ``edge_map`` and the graphFilter consume, with the decoded arrays
-produced lazily (XLA fuses the cumsum decode into the consumer, so nothing
-int32-wide is ever materialized in HBM on the jit path; the Pallas kernel in
+produced lazily inside the consuming program (the Pallas kernel in
 ``repro.kernels.compressed_spmv`` streams the raw uint16 deltas directly).
+XLA decides what it fuses: on a v5e the dense sweep still writes the
+decoded int32 targets to HBM once per round, as the scatter's index
+operand (PERF.md, the decode layer).
 
 Compression ratio: 32-bit targets → ~16.25 bits/edge + exceptions, i.e.
 ~2× on locality-friendly orderings (the paper reports 2.7–2.9× with
@@ -33,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import ingest_span, scoped
 from .csr import CSRGraph, sharded_block_counts
 
 ESCAPE = np.uint16(0xFFFF)
@@ -111,9 +114,9 @@ class CompressedCSR:
 
     # ------------------------------------------------------------------
     # Backend view — same surface the uncompressed CSRGraph offers.  The
-    # decoded arrays are *lazy*: under jit the cumsum decode fuses into
-    # whatever consumes it (edgeMap's gather/segment-reduce), so the wide
-    # int32 targets never round-trip through HBM.
+    # decoded arrays are *lazy*: under jit the cumsum decode is traced into
+    # whatever consumes it (edgeMap's gather/segment-reduce), under the
+    # scope ``sage.decode``.
     # ------------------------------------------------------------------
     @property
     def block_dst(self) -> jnp.ndarray:
@@ -238,42 +241,47 @@ def compress(g: CSRGraph) -> CompressedCSR:
     their weights uncompressed alongside the delta-packed targets.
     """
     NB, FB = g.num_blocks, g.block_size
-    dst = np.asarray(g.edge_dst).reshape(NB, FB).astype(np.int64)
-    vc = (dst < g.n).sum(axis=1).astype(np.int64)  # front-packed real slots
-    last = np.where(vc > 0, dst[np.arange(NB), np.maximum(vc - 1, 0)], 0)
-    lane = np.arange(FB)[None, :]
-    dst_enc = np.where(lane < vc[:, None], dst, last[:, None])
-    first = dst_enc[:, 0].astype(np.int32)
-    prev = dst_enc[:, :-1]
-    cur = dst_enc[:, 1:]
-    raw = cur - prev
-    raw = np.concatenate([np.zeros((NB, 1), np.int64), raw], axis=1)
-    over = (raw >= int(ESCAPE)) | (raw < 0)
-    deltas = np.where(over, int(ESCAPE), raw).astype(np.uint16)
-    eb, es = np.nonzero(over)
-    return CompressedCSR(
-        block_first=jnp.asarray(first),
-        deltas=jnp.asarray(deltas),
-        valid_count=jnp.asarray(vc.astype(np.uint16)),
-        exc_block=jnp.asarray(eb.astype(np.int32)),
-        exc_slot=jnp.asarray(es.astype(np.int32)),
-        exc_value=jnp.asarray(raw[eb, es].astype(np.int32)),
-        block_src=g.block_src,
-        degrees=g.degrees,
-        n=g.n,
-        m=g.m,
-        num_blocks=NB,
-        block_size=FB,
-        n_exceptions=int(eb.shape[0]),
-        block_weights=g.block_w if g.weighted else None,
-        weighted=g.weighted,
-    )
+    with ingest_span("fetch"):
+        dst = np.asarray(g.edge_dst)
+    with ingest_span("encode"):
+        dst = dst.reshape(NB, FB).astype(np.int64)
+        vc = (dst < g.n).sum(axis=1).astype(np.int64)  # front-packed real slots
+        last = np.where(vc > 0, dst[np.arange(NB), np.maximum(vc - 1, 0)], 0)
+        lane = np.arange(FB)[None, :]
+        dst_enc = np.where(lane < vc[:, None], dst, last[:, None])
+        first = dst_enc[:, 0].astype(np.int32)
+        prev = dst_enc[:, :-1]
+        cur = dst_enc[:, 1:]
+        raw = cur - prev
+        raw = np.concatenate([np.zeros((NB, 1), np.int64), raw], axis=1)
+        over = (raw >= int(ESCAPE)) | (raw < 0)
+        deltas = np.where(over, int(ESCAPE), raw).astype(np.uint16)
+        eb, es = np.nonzero(over)
+        c = CompressedCSR(
+            block_first=jnp.asarray(first),
+            deltas=jnp.asarray(deltas),
+            valid_count=jnp.asarray(vc.astype(np.uint16)),
+            exc_block=jnp.asarray(eb.astype(np.int32)),
+            exc_slot=jnp.asarray(es.astype(np.int32)),
+            exc_value=jnp.asarray(raw[eb, es].astype(np.int32)),
+            block_src=g.block_src,
+            degrees=g.degrees,
+            n=g.n,
+            m=g.m,
+            num_blocks=NB,
+            block_size=FB,
+            n_exceptions=int(eb.shape[0]),
+            block_weights=g.block_w if g.weighted else None,
+            weighted=g.weighted,
+        )
+    return c
 
 
 def _lane_iota(c: CompressedCSR) -> jnp.ndarray:
     return jnp.arange(c.block_size, dtype=jnp.int32)
 
 
+@scoped("sage.decode")
 def decode_blocks(c: CompressedCSR) -> jnp.ndarray:
     """Decode ALL blocks → int32[NB, FB] targets (vectorized cumsum).
 
@@ -318,6 +326,7 @@ def exception_dense(c: CompressedCSR) -> bool:
     return c.n_exceptions > max(16, min(c.num_blocks // 4, 4096))
 
 
+@scoped("sage.decode")
 def decode_block_tile(c: CompressedCSR, bids: jnp.ndarray) -> jnp.ndarray:
     """Decode a tile of blocks → int32[C, FB] (the chunk-loop path, §4.1).
 

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import ingest_span
+
 DEFAULT_BLOCK_SIZE = 128  # lanes; multiple of 32 so the filter bitset packs into words
 
 
@@ -152,78 +154,81 @@ def build_csr(
     reverse edges are added (and exact duplicates removed), matching the
     paper's symmetrized inputs.
     """
-    src = np.asarray(src, dtype=np.int64)
-    dst = np.asarray(dst, dtype=np.int64)
-    if w is None:
-        weighted = False
-        w = np.ones_like(src, dtype=np.float32)
-    else:
-        weighted = True
-        w = np.asarray(w, dtype=np.float32)
+    with ingest_span("dedupe"):
+        src = np.asarray(src, dtype=np.int64)
+        dst = np.asarray(dst, dtype=np.int64)
+        if w is None:
+            weighted = False
+            w = np.ones_like(src, dtype=np.float32)
+        else:
+            weighted = True
+            w = np.asarray(w, dtype=np.float32)
 
-    if symmetrize:
-        src, dst, w = (
-            np.concatenate([src, dst]),
-            np.concatenate([dst, src]),
-            np.concatenate([w, w]),
+        if symmetrize:
+            src, dst, w = (
+                np.concatenate([src, dst]),
+                np.concatenate([dst, src]),
+                np.concatenate([w, w]),
+            )
+        # drop self loops, dedupe; np.unique returns the keys sorted, so the
+        # edges come out ordered by (src, dst)
+        keep = src != dst
+        src, dst, w = src[keep], dst[keep], w[keep]
+        key = src * n + dst
+        _, uniq = np.unique(key, return_index=True)
+        src, dst, w = src[uniq], dst[uniq], w[uniq]
+        m = int(src.shape[0])
+
+    with ingest_span("layout"):
+        deg = np.bincount(src, minlength=n).astype(np.int64)
+        nblk = np.maximum((deg + block_size - 1) // block_size, 0)
+        block_offsets = np.zeros(n + 1, dtype=np.int64)
+        np.cumsum(nblk, out=block_offsets[1:])
+        num_blocks = int(block_offsets[-1])
+        num_blocks = max(num_blocks, 1)  # keep shapes non-degenerate
+        if int(block_offsets[-1]) == 0:
+            block_offsets[-1] = 1  # single dummy block owned by sentinel
+
+        slots = num_blocks * block_size
+        edge_src = np.full(slots, n, dtype=np.int32)
+        edge_dst = np.full(slots, n, dtype=np.int32)
+        edge_w = np.zeros(slots, dtype=np.float32)
+
+        offsets = np.zeros(n + 1, dtype=np.int64)
+        np.cumsum(nblk * block_size, out=offsets[1:])
+        # scatter edges into their padded slots
+        starts = offsets[src]
+        within = np.zeros(m, dtype=np.int64)
+        if m:
+            # position of each edge within its vertex's run (src-sorted)
+            first_of_run = np.concatenate([[True], src[1:] != src[:-1]])
+            run_ids = np.cumsum(first_of_run) - 1
+            run_starts = np.flatnonzero(first_of_run)
+            within = np.arange(m) - run_starts[run_ids]
+        pos = starts + within
+        edge_src[pos] = src.astype(np.int32)
+        edge_dst[pos] = dst.astype(np.int32)
+        edge_w[pos] = w
+
+        block_src = np.full(num_blocks, n, dtype=np.int32)
+        for_v = np.repeat(np.arange(n, dtype=np.int32), nblk)
+        block_src[: for_v.shape[0]] = for_v
+
+        g = CSRGraph(
+            offsets=jnp.asarray(offsets, dtype=jnp.int32),
+            block_offsets=jnp.asarray(block_offsets, dtype=jnp.int32),
+            block_src=jnp.asarray(block_src),
+            edge_src=jnp.asarray(edge_src),
+            edge_dst=jnp.asarray(edge_dst),
+            edge_w=jnp.asarray(edge_w),
+            degrees=jnp.asarray(deg, dtype=jnp.int32),
+            n=int(n),
+            m=m,
+            num_blocks=num_blocks,
+            block_size=int(block_size),
+            weighted=weighted,
         )
-    # drop self loops, dedupe; np.unique returns the keys sorted, so the
-    # edges come out ordered by (src, dst)
-    keep = src != dst
-    src, dst, w = src[keep], dst[keep], w[keep]
-    key = src * n + dst
-    _, uniq = np.unique(key, return_index=True)
-    src, dst, w = src[uniq], dst[uniq], w[uniq]
-    m = int(src.shape[0])
-
-    deg = np.bincount(src, minlength=n).astype(np.int64)
-    nblk = np.maximum((deg + block_size - 1) // block_size, 0)
-    block_offsets = np.zeros(n + 1, dtype=np.int64)
-    np.cumsum(nblk, out=block_offsets[1:])
-    num_blocks = int(block_offsets[-1])
-    num_blocks = max(num_blocks, 1)  # keep shapes non-degenerate
-    if int(block_offsets[-1]) == 0:
-        block_offsets[-1] = 1  # single dummy block owned by sentinel
-
-    slots = num_blocks * block_size
-    edge_src = np.full(slots, n, dtype=np.int32)
-    edge_dst = np.full(slots, n, dtype=np.int32)
-    edge_w = np.zeros(slots, dtype=np.float32)
-
-    offsets = np.zeros(n + 1, dtype=np.int64)
-    np.cumsum(nblk * block_size, out=offsets[1:])
-    # scatter edges into their padded slots
-    starts = offsets[src]
-    within = np.zeros(m, dtype=np.int64)
-    if m:
-        # position of each edge within its vertex's run (src-sorted)
-        first_of_run = np.concatenate([[True], src[1:] != src[:-1]])
-        run_ids = np.cumsum(first_of_run) - 1
-        run_starts = np.flatnonzero(first_of_run)
-        within = np.arange(m) - run_starts[run_ids]
-    pos = starts + within
-    edge_src[pos] = src.astype(np.int32)
-    edge_dst[pos] = dst.astype(np.int32)
-    edge_w[pos] = w
-
-    block_src = np.full(num_blocks, n, dtype=np.int32)
-    for_v = np.repeat(np.arange(n, dtype=np.int32), nblk)
-    block_src[: for_v.shape[0]] = for_v
-
-    return CSRGraph(
-        offsets=jnp.asarray(offsets, dtype=jnp.int32),
-        block_offsets=jnp.asarray(block_offsets, dtype=jnp.int32),
-        block_src=jnp.asarray(block_src),
-        edge_src=jnp.asarray(edge_src),
-        edge_dst=jnp.asarray(edge_dst),
-        edge_w=jnp.asarray(edge_w),
-        degrees=jnp.asarray(deg, dtype=jnp.int32),
-        n=int(n),
-        m=m,
-        num_blocks=num_blocks,
-        block_size=int(block_size),
-        weighted=weighted,
-    )
+    return g
 
 
 def graph_spec(n: int, num_blocks: int, block_size: int, weighted: bool = False):

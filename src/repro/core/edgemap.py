@@ -40,6 +40,11 @@ decodes block tiles *inside* the chunk loop, so the peak intermediate stays
 through ONE sweep of the same bodies: the edge stream is read once per
 round and fanned across the B frontier/state columns, the throughput lever
 the serving subsystem (``repro.serving``) is built on.
+
+The dense bodies trace under the named scope ``sage.sweep.dense``, the
+chunked ones under ``sage.sweep.sparse``, and a compressed graph's decode
+under ``sage.decode`` (``repro.obs.trace``), so a profiler trace splits a
+round's device time by mode.
 """
 from __future__ import annotations
 
@@ -49,6 +54,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.trace import scoped
 from ..tuning.defaults import DEFAULT_CHUNK_BLOCKS, DEFAULT_DENSE_FRAC
 from .backend import GraphLike, dense_block_view, tile_block_reader
 from .graph_filter import GraphFilter, edge_active_words, unpack_word_bits
@@ -116,8 +122,10 @@ def _streaming_decoder(g: GraphLike, edge_active, interpret: bool | None = None)
     # exception rows are id-independent: decode them exactly ONCE here, so
     # the chunk loop's per-iteration patch is a cheap match + scatter (the
     # O(NE·F_B) exact decode becomes a hoisted loop input, not loop body)
-    exact = _exception_row_targets(g, words) if g.n_exceptions else None
+    with jax.named_scope("sage.decode"):
+        exact = _exception_row_targets(g, words) if g.n_exceptions else None
 
+    @scoped("sage.decode")
     def tile(bids):
         return compressed_chunked_stream_tile(
             g, bids, words, exact_rows=exact, interpret=interpret
@@ -138,6 +146,7 @@ def _combine(monoid, a, b):
     raise ValueError(monoid)
 
 
+@scoped("sage.sweep.dense")
 def edgemap_dense(
     g: GraphLike,
     frontier_mask: jnp.ndarray,
@@ -151,7 +160,7 @@ def edgemap_dense(
     """Pull-style pass over all edge slots.  Returns (out[n,...], touched[n]).
 
     Reads the backend's block view: for ``CompressedCSR`` the target decode
-    is a lazy cumsum fused into the gather/segment-reduce below.
+    is a lazy cumsum traced into the gather/segment-reduce below.
 
     ``interpret`` is accepted for call-site symmetry with the chunked /
     streamed paths but is a no-op here: this body is pure jnp (the fused
@@ -187,6 +196,7 @@ def edgemap_dense(
     return out, touched
 
 
+@scoped("sage.sweep.sparse")
 def edgemap_chunked(
     g: GraphLike,
     frontier_mask: jnp.ndarray,
@@ -330,18 +340,6 @@ def edgemap_reduce(
     dense_frac = DEFAULT_DENSE_FRAC if dense_frac is None else dense_frac
     chunk_blocks = DEFAULT_CHUNK_BLOCKS if chunk_blocks is None else chunk_blocks
     auto_sparse = "sparse" if auto_sparse is None else auto_sparse
-    from ..obs import get_registry
-
-    _reg = get_registry()
-    if _reg.enabled and not isinstance(frontier_mask, jax.core.Tracer):
-        # eager single-device sweep — count by resolved mode ('auto' means
-        # the dense/sparse pick happens in-trace per round); jitted rounds
-        # show up in round_loop's metrics instead, never double-counted
-        _reg.counter(
-            "sage_edgemap_calls_total",
-            "eager edgemap_reduce dispatches by resolved mode",
-            labels=("mode",),
-        ).inc(mode=mode)
     if mode == "dense":
         return edgemap_dense(
             g, frontier_mask, x, monoid=monoid, map_fn=map_fn, edge_active=edge_active
@@ -379,6 +377,7 @@ def edgemap_reduce(
     )
 
 
+@scoped("sage.sweep.dense")
 def edgemap_dense_batched(
     g: GraphLike,
     frontier_masks: jnp.ndarray,
@@ -436,6 +435,7 @@ def edgemap_dense_batched(
     return out.T, touched.T
 
 
+@scoped("sage.sweep.sparse")
 def edgemap_chunked_batched_streamed(
     g: GraphLike,
     frontier_masks: jnp.ndarray,
@@ -644,6 +644,9 @@ def edgemap_reduce_batched(
     ml_axis = None if map_lanes is None else 0
     ml0 = jnp.zeros(xb.shape[0], bool) if map_lanes is None else map_lanes
 
+    # under vmap the lanes' scope reads vmap(sage.sweep.sparse): open the
+    # plain scope outside it too
+    @scoped("sage.sweep.sparse")
     def sparse_vmap(fm, xv):
         return jax.vmap(sparse_one, in_axes=(0, 0, ml_axis))(fm, xv, ml0)
 

@@ -43,7 +43,6 @@ construction (tested property).
 from __future__ import annotations
 
 import dataclasses
-import time
 from functools import partial
 from typing import Any
 
@@ -55,7 +54,7 @@ from jax.sharding import PartitionSpec as P
 
 import numpy as np
 
-from ..obs import DEFAULT_LATENCY_BUCKETS, exp_buckets, get_registry
+from ..obs import exp_buckets, get_registry
 from ..tuning.defaults import DEFAULT_CHUNK_BLOCKS, DEFAULT_DENSE_FRAC
 from .compressed import CompressedCSR, exception_dense
 from .csr import CSRGraph, graph_spec, sharded_block_counts
@@ -850,19 +849,7 @@ def _sharded_edgemap_call(
         # the fast axis but the static replication check can't prove it
         check_vma=False,
     )
-    out = fn(*operands)
-    reg = get_registry()
-    if reg.enabled and not any(
-        isinstance(leaf, jax.core.Tracer) for leaf in jax.tree.leaves(out)
-    ):
-        # eager sharded round: count it (cheap) — wall timing lives in
-        # round_loop / trace_session, not per-edgeMap
-        reg.counter(
-            "sage_sharded_edgemap_calls_total",
-            "eager sharded edgeMap rounds dispatched",
-            labels=("batched",),
-        ).inc(batched=str(local_reduce.__name__.endswith("batched")).lower())
-    return out
+    return fn(*operands)
 
 
 def sharded_edgemap_reduce(
@@ -965,34 +952,24 @@ _ROUND_BUCKETS = exp_buckets(1.0, 10_000.0, per_decade=6)
 
 
 def _eager_round_loop_observer(state):
-    """Host-side timing hook for one ``round_loop`` call, or ``None``.
+    """Host-side round count of one ``round_loop`` call, or ``None``.
 
-    Timing a loop of ``lax.while_loop`` rounds from the host is only
-    meaningful (and only *possible*) when the call executes eagerly — under
+    Counting is only possible when the call executes eagerly — under
     ``jax.jit`` / ``eval_shape`` the state leaves are tracers and the
-    "call" is a trace, not an execution.  And it is only *wanted* when the
-    active registry is live.  Returns a ``finish(final, path)`` callable
-    that blocks on the result, records wall seconds into
-    ``sage_round_loop_seconds{path=}`` and — when the final state carries a
-    scalar integer leaf (BFS's ``rnd``, PageRank's ``iters``) — the round
-    count into ``sage_round_loop_rounds{path=}``.  Per-round GPU-accurate
-    timing comes from ``trace_session`` + the ``sage.round*`` scopes; this
-    is the always-on cheap aggregate.
+    "call" is a trace, not an execution — and only wanted when the active
+    registry is live.  Returns a ``finish(final, path)`` callable that,
+    when the final state carries a scalar integer leaf (BFS's ``rnd``,
+    PageRank's ``iters``), records the round count into
+    ``sage_round_loop_rounds{path=}`` (reading it waits for the result).
+    Device time per round comes from a trace and the ``sage.round`` scope.
     """
     reg = get_registry()
     if not reg.enabled or any(
         isinstance(leaf, jax.core.Tracer) for leaf in jax.tree.leaves(state)
     ):
         return None
-    t0 = time.perf_counter()
 
     def finish(final, path):
-        final = jax.block_until_ready(final)
-        reg.histogram(
-            "sage_round_loop_seconds",
-            "wall seconds per eager round_loop call",
-            labels=("path",), buckets=DEFAULT_LATENCY_BUCKETS,
-        ).observe(time.perf_counter() - t0, path=path)
         rounds = [
             int(leaf)
             for leaf in jax.tree.leaves(final)

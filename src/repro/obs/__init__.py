@@ -9,9 +9,12 @@ Every runtime layer reports here (ISSUE 9):
   the process-global default registry plus injectable instances;
   :func:`noop_registry` installs disabled mode (one attribute lookup per
   hot-path record, bit-identical results, no clock reads).
-* :func:`trace_session` / :func:`annotate` — ``jax.profiler`` capture as a
-  context manager, usable from serving, carrying the planner's
-  ``sage.round`` / ``sage.shard_combine`` named scopes.
+* :func:`trace_session` / :func:`annotate` / :func:`ingest_span` —
+  ``jax.profiler`` capture as a context manager, usable from serving, and
+  named host spans; the device work it captures carries the engine's named
+  scopes (``sage.round``, ``sage.round.sweep``, ``sage.sweep.dense``,
+  ``sage.sweep.sparse``, ``sage.decode``, ``sage.shard_combine``; see
+  ``repro.obs.trace``).
 * ``python -m repro.obs.dump`` — run a small instrumented serving replay
   (or nothing) and print the registry as Prometheus text or JSON.
 
@@ -23,7 +26,11 @@ What reports where:
   (``sage_psam_drift_words_per_second``).
 * ``QueryEngine`` — batch-size histograms, lane/padding counters,
   compile-cache hits/misses (steady-state retraces are a *metric*).
-* ``repro.core.plan`` — host-side round-loop timings and rounds-per-call.
+* ``repro.core.plan`` — rounds per eager ``round_loop`` call
+  (``sage_round_loop_rounds``).
+* ``repro.core.csr.build_csr`` / ``repro.core.compressed.compress`` — the
+  seconds of each phase of the last ingest
+  (``sage_ingest_last_seconds{phase=dedupe|layout|fetch|encode}``).
 * ``PSAMCost`` — every ``charge_*`` mirrored into
   ``sage_psam_*_words_total{charge=...}`` counters.
 * ``repro.delta`` + the mutable serving path — applied edits by kind
@@ -49,7 +56,7 @@ from .metrics import (
     set_registry,
     use_registry,
 )
-from .trace import annotate, trace_session
+from .trace import annotate, ingest_span, trace_session
 
 __all__ = [
     "Counter",
@@ -65,4 +72,5 @@ __all__ = [
     "DEFAULT_LATENCY_BUCKETS",
     "trace_session",
     "annotate",
+    "ingest_span",
 ]

@@ -3,8 +3,9 @@
 The observability substrate every layer reports through (ISSUE 9): the
 serving tier records per-``(op, tenant)`` latency histograms and admission
 outcomes, the engine records batch shapes and compile-cache hits, the
-planner's round loops record host-side timings, and ``PSAMCost`` mirrors
-every ``charge_*`` into labeled counters — so the paper's analytic read
+planner's round loops record rounds per call, the ingest records its
+phases' seconds, and ``PSAMCost`` mirrors every ``charge_*`` into
+labeled counters — so the paper's analytic read
 model streams out of a live service *next to* measured seconds, which is
 what makes the PSAM-vs-wall-clock drift observable while serving.
 

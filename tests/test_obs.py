@@ -377,10 +377,8 @@ def test_round_loop_metrics_record_eagerly():
     reg = Registry()
     with use_registry(reg):
         bfs(g, 0)
-    h = reg.get("sage_round_loop_seconds")
-    assert h is not None and h.count(path="sequential") >= 1
     rounds = reg.get("sage_round_loop_rounds")
-    assert rounds is not None and rounds.count() >= 1
+    assert rounds is not None and rounds.count(path="sequential") >= 1
     # BFS on a connected-ish rmat graph runs a plausible round count
     assert 1 <= rounds.percentile(50) <= 256
 
