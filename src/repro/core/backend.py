@@ -19,8 +19,8 @@ algorithm suite) accept any object satisfying ``GraphBackend``:
 The two structural hooks that differ per backend live here:
 
 * ``dense_block_view``  — the full (NB, F_B) target/weight view for the
-  dense (pull) pass.  For the compressed backend this is the lazy cumsum
-  decode, traced into the consuming gather/segment-reduce; the
+  dense pass, which row-gathers it by degree band.  For the compressed
+  backend this is the cumsum decode of every block, once per call; the
   Pallas ``compressed_spmv`` kernel is the explicitly streamed variant.
 * ``tile_block_reader`` — reads C-block tiles for the chunked (sparse) pass.
   For the compressed backend this decodes *inside the chunk loop*

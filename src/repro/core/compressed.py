@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.trace import ingest_span, scoped
+from .bands import BandIndex, check_band_index
 from .csr import CSRGraph, sharded_block_counts
 
 ESCAPE = np.uint16(0xFFFF)
@@ -53,6 +54,7 @@ ESCAPE = np.uint16(0xFFFF)
         "block_src",
         "degrees",
         "block_weights",
+        "bands",
     ],
     meta_fields=[
         "n",
@@ -87,6 +89,9 @@ class CompressedCSR:
     # shard keeps the original decode-strategy choice (a shard's padded
     # exception list and shrunken block count would skew the ratio test)
     exception_dense_hint: bool | None = None
+    # the dense sweep's degree bands (``repro.core.bands``); None takes the
+    # padded sweep
+    bands: BandIndex | None = None
 
     @property
     def compressed_bytes(self) -> int:
@@ -171,6 +176,7 @@ class CompressedCSR:
         with identical meta.  Block counts that don't divide pad with empty
         blocks (valid_count 0, owner = sentinel n) — the tail shard is never
         truncated.  Vertex metadata (``degrees``) stays replicated per shard.
+        Shards carry no band index: their dense sweep is the padded one.
         """
         if num_shards < 1:
             raise ValueError(f"num_shards must be >= 1, got {num_shards}")
@@ -222,6 +228,7 @@ class CompressedCSR:
                     n_exceptions=ne_max,
                     block_weights=None if bw is None else jnp.asarray(bw[lo:hi]),
                     exception_dense_hint=exception_dense(self),
+                    bands=None,
                 )
             )
         return shards
@@ -238,7 +245,9 @@ def compress(g: CSRGraph) -> CompressedCSR:
     exception list stays tied to true ≥2¹⁶ adjacency gaps — without this,
     every padded block on a graph with n > 2¹⁶ would land on the exception
     list and the "rare path" would stop being rare.  Weighted graphs keep
-    their weights uncompressed alongside the delta-packed targets.
+    their weights uncompressed alongside the delta-packed targets.  The
+    CSR's band index (``repro.core.bands``) is carried over, checked
+    against the encoded valid counts.
     """
     NB, FB = g.num_blocks, g.block_size
     with ingest_span("fetch"):
@@ -257,6 +266,8 @@ def compress(g: CSRGraph) -> CompressedCSR:
         over = (raw >= int(ESCAPE)) | (raw < 0)
         deltas = np.where(over, int(ESCAPE), raw).astype(np.uint16)
         eb, es = np.nonzero(over)
+        if g.bands is not None:
+            check_band_index(g.bands, vc, g.block_src, FB)
         c = CompressedCSR(
             block_first=jnp.asarray(first),
             deltas=jnp.asarray(deltas),
@@ -273,6 +284,7 @@ def compress(g: CSRGraph) -> CompressedCSR:
             n_exceptions=int(eb.shape[0]),
             block_weights=g.block_w if g.weighted else None,
             weighted=g.weighted,
+            bands=g.bands,
         )
     return c
 

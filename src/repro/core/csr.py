@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.trace import ingest_span
+from .bands import BandIndex, band_index
 
 DEFAULT_BLOCK_SIZE = 128  # lanes; multiple of 32 so the filter bitset packs into words
 
@@ -52,6 +53,7 @@ def sharded_block_counts(num_blocks: int, num_shards: int) -> tuple[int, int]:
         "edge_dst",
         "edge_w",
         "degrees",
+        "bands",
     ],
     meta_fields=["n", "m", "num_blocks", "block_size", "weighted"],
 )
@@ -73,6 +75,9 @@ class CSRGraph:
     num_blocks: int
     block_size: int
     weighted: bool
+    # the dense sweep's degree bands (``repro.core.bands``); None takes the
+    # padded sweep
+    bands: BandIndex | None = None
 
     # ------------------------------------------------------------------
     @property
@@ -107,7 +112,8 @@ class CSRGraph:
         *global* vertex space: same ``n``, same sentinel, same frontier
         semantics.  The planner (``repro.core.plan``) stacks shards into one
         pytree and runs the ordinary edgeMap bodies per shard inside
-        ``shard_map``.
+        ``shard_map``.  Shards carry no band index (their band sizes would
+        differ, and shards stack): their dense sweep is the padded one.
         """
         if num_shards < 1:
             raise ValueError(f"num_shards must be >= 1, got {num_shards}")
@@ -134,6 +140,7 @@ class CSRGraph:
                     edge_dst=jnp.asarray(edst[lo:hi].reshape(-1)),
                     edge_w=jnp.asarray(ew[lo:hi].reshape(-1)),
                     num_blocks=per,
+                    bands=None,
                 )
             )
         return shards
@@ -213,6 +220,11 @@ def build_csr(
         block_src = np.full(num_blocks, n, dtype=np.int32)
         for_v = np.repeat(np.arange(n, dtype=np.int32), nblk)
         block_src[: for_v.shape[0]] = for_v
+        # a block's valid count from its owner's degree: every block of a
+        # vertex is full but its last
+        valid = np.zeros(num_blocks, dtype=np.int64)
+        rank = np.arange(for_v.shape[0]) - block_offsets[for_v]
+        valid[: for_v.shape[0]] = np.minimum(deg[for_v] - rank * block_size, block_size)
 
         g = CSRGraph(
             offsets=jnp.asarray(offsets, dtype=jnp.int32),
@@ -227,6 +239,7 @@ def build_csr(
             num_blocks=num_blocks,
             block_size=int(block_size),
             weighted=weighted,
+            bands=band_index(valid, block_src, block_size),
         )
     return g
 

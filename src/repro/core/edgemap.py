@@ -2,10 +2,17 @@
 
 Four execution modes, mirroring the paper:
 
-* ``dense``  — the pull-style pass over *all* edge slots (one masked
-  segment-reduce).  Work O(m); the O(n)-words output discipline holds because
-  the per-edge intermediates are fused away on TPU (and streamed block-wise by
-  the Pallas kernel in ``repro.kernels.edge_block_spmv``).
+* ``dense``  — the pass over every block (one masked segment-reduce, an
+  unsorted scatter keyed by target).  A scatter costs the same per update
+  whether the slot is real or sentinel padding, so the pass reads the
+  blocks through the graph's degree bands (``repro.core.bands``): each
+  band's blocks are row-gathered whole and packed ``F_B // w`` to a row,
+  ``w`` the band's width (the power-of-two ceiling of their valid counts),
+  and the bands are concatenated into one scatter.  A graph without a band
+  index (the shards of a sharded plan, ``DeltaGraph``, stand-in specs)
+  takes the padded pass over every slot.  Work O(m + NB); the per-slot
+  targets, values and masks are materialized in HBM for the scatter, not
+  fused away.
 * ``sparse`` — EDGEMAPCHUNKED: only blocks owned by frontier vertices are
   touched.  The active block list is O(n) words (block size == d_avg ⇒
   #blocks = O(n), App. A), and blocks are processed in fixed-size chunks so
@@ -32,7 +39,7 @@ Ligra's C(v).
 
 Every mode accepts either execution backend (``CSRGraph | CompressedCSR``,
 see ``repro.core.backend``): the dense pass reads the backend's block view
-(a lazy, fused cumsum decode for compressed graphs) and the chunked pass
+(for compressed graphs the cumsum decode, once per call) and the chunked pass
 decodes block tiles *inside* the chunk loop, so the peak intermediate stays
 ``chunk_blocks × F_B`` words regardless of storage format.
 
@@ -54,6 +61,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.metrics import get_registry
 from ..obs.trace import scoped
 from ..tuning.defaults import DEFAULT_CHUNK_BLOCKS, DEFAULT_DENSE_FRAC
 from .backend import GraphLike, dense_block_view, tile_block_reader
@@ -146,6 +154,92 @@ def _combine(monoid, a, b):
     raise ValueError(monoid)
 
 
+def _dense_routing(g: GraphLike):
+    """The dense sweep's row groups ``[(ids, owners, width)]``, in order.
+
+    With a band index (``repro.core.bands``) the groups are its bands:
+    ``ids`` a band's block ids and ``owners`` theirs, both padded with
+    out-of-range fill to whole packed rows (``_pack_rows``) of 8-row tiles,
+    and ``width`` the lanes read of each block.  Without one (shards of a
+    sharded plan, ``DeltaGraph``, stand-in specs) it is one group of every
+    block at full width, in storage order (``ids`` None): the padded sweep.
+    The slots the groups hold are recorded, at trace time, in the gauge
+    ``sage_dense_sweep_slots{kind=scattered|padded}``."""
+    bands = getattr(g, "bands", None)
+    fb = g.block_size
+    if bands is None:
+        groups = [(None, g.block_src, fb)]
+    else:
+        groups = []
+        for w, s, r in bands.spans():
+            tile = 8 * (fb // w)
+            pad = -r % tile
+            ids = jnp.pad(bands.block_ids[s : s + r], (0, pad), constant_values=g.num_blocks)
+            owners = jnp.pad(bands.owners[s : s + r], (0, pad), constant_values=g.n)
+            groups.append((ids, owners, w))
+    gauge = get_registry().gauge(
+        "sage_dense_sweep_slots",
+        "slots the last traced dense sweep scatters, and the padded slots of its graph",
+        labels=("kind",),
+    )
+    scattered = sum(own.shape[0] // (fb // w) * fb for _, own, w in groups)
+    gauge.set(scattered, kind="scattered")
+    gauge.set(g.num_blocks * fb, kind="padded")
+    return groups
+
+
+def _cat(parts, axis=0):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
+
+
+def _lane_segment(fb: int, w: int, axis: int, ndim: int):
+    """Each lane's segment ``lane // w``, shaped to broadcast along ``axis``
+    of an ``ndim``-dimensional array."""
+    seg = jnp.arange(fb, dtype=jnp.int32) // w
+    return seg.reshape((1,) * axis + (fb,) + (1,) * (ndim - axis - 1))
+
+
+def _pack_rows(rows, w: int):
+    """Pack a band's ``(k·g, F_B)`` rows, ``k = F_B // w``, into ``(g, F_B)``:
+    lanes ``[j·w, (j+1)·w)`` of row r hold the first w lanes of row
+    ``j·g + r``.  Every array keeps F_B lanes, so no narrow ``(R, w)``
+    array is laid out (padded to the full lane width) on the way to the
+    scatter; a lane rotation does the packing."""
+    fb = rows.shape[1]
+    k = fb // w
+    if k == 1:
+        return rows
+    parts = rows.reshape((k, rows.shape[0] // k) + rows.shape[1:])
+    seg = _lane_segment(fb, w, 1, rows.ndim)
+    out = parts[0]
+    for j in range(1, k):
+        out = jnp.where(seg == j, jnp.roll(parts[j], j * w, axis=1), out)
+    return out
+
+
+def _pack_blocks(v, w: int, fb: int, axis: int = 0):
+    """Per-block values, blocks along ``axis``, laid out as ``_pack_rows``
+    lays out their rows: ``axis`` becomes ``(g, F_B)``."""
+    k = fb // w
+    g = v.shape[axis] // k
+    seg = _lane_segment(fb, w, axis + 1, v.ndim + 1)
+    out = None
+    for j in range(k):
+        part = jnp.expand_dims(lax.slice_in_dim(v, j * g, (j + 1) * g, axis=axis), axis + 1)
+        out = part if out is None else jnp.where(seg == j, part, out)
+    return jnp.broadcast_to(out, v.shape[:axis] + (g, fb) + v.shape[axis + 1 :])
+
+
+def _slot_rows(arr, groups, fill):
+    """A (NB, F_B) block array as flat slots in routing order."""
+    return _cat([
+        _pack_rows(
+            arr if ids is None else jnp.take(arr, ids, axis=0, mode="fill", fill_value=fill), w
+        ).reshape(-1)
+        for ids, _, w in groups
+    ])
+
+
 @scoped("sage.sweep.dense")
 def edgemap_dense(
     g: GraphLike,
@@ -157,31 +251,37 @@ def edgemap_dense(
     edge_active: jnp.ndarray | None = None,
     interpret: bool | None = None,
 ):
-    """Pull-style pass over all edge slots.  Returns (out[n,...], touched[n]).
+    """Dense pass over every block's slots.  Returns (out[n,...], touched[n]).
 
-    Reads the backend's block view: for ``CompressedCSR`` the target decode
-    is a lazy cumsum traced into the gather/segment-reduce below.
+    Reads the backend's block view (for ``CompressedCSR`` the decoded
+    targets, once per call) through the degree bands of ``_dense_routing``
+    and runs one segment-reduce over the slots read.
 
     ``interpret`` is accepted for call-site symmetry with the chunked /
-    streamed paths but is a no-op here: this body is pure jnp (the fused
-    decode+reduce IS the lowering), there is no Pallas kernel to steer.
+    streamed paths but is a no-op here: this body is pure jnp, there is no
+    Pallas kernel to steer.
     """
     del interpret
-    n, FB = g.n, g.block_size
+    n, fb = g.n, g.block_size
+    feat = x.shape[1:]
     ident = monoid_identity(monoid, x.dtype)
+    groups = _dense_routing(g)
     block_dst, block_w = dense_block_view(g)
-    edge_dst = block_dst.reshape(-1)
-    frontier_blk = _gather_rows(frontier_mask, g.block_src, False)
-    act = (frontier_blk[:, None] & (block_dst < jnp.int32(n))).reshape(-1)
+
+    def spread(v, fill):  # per-vertex values at each group's owners -> slots
+        return _cat([
+            _pack_blocks(_gather_rows(v, own, fill), w, fb).reshape((-1,) + v.shape[1:])
+            for _, own, w in groups
+        ])
+
+    edge_dst = _slot_rows(block_dst, groups, n)
+    act = spread(frontier_mask, False) & (edge_dst < jnp.int32(n))
     ea = _edge_active_view(g, edge_active)
     if ea is not None:
-        act = act & ea.reshape(-1)
-    xs_blk = _gather_rows(x, g.block_src, ident)
-    xs = jnp.broadcast_to(
-        xs_blk[:, None], (g.num_blocks, FB) + x.shape[1:]
-    ).reshape((g.num_blocks * FB,) + x.shape[1:])
-    edge_w = block_w.reshape(-1)
-    w = edge_w if x.ndim == 1 else edge_w[..., None]
+        act = act & _slot_rows(ea, groups, False)
+    xs = spread(x, ident)
+    edge_w = _slot_rows(block_w, groups, 0.0)
+    w = edge_w if not feat else edge_w[..., None]
     vals = map_fn(xs, w)
     if vals.ndim > act.ndim:
         sel = act.reshape(act.shape + (1,) * (vals.ndim - act.ndim))
@@ -407,23 +507,28 @@ def edgemap_dense_batched(
     relaxation over distances) — share ONE edge sweep while each lane runs
     its own recurrence.
     """
-    n, NB, FB = g.n, g.num_blocks, g.block_size
-    B = xb.shape[0]
+    n, fb, B = g.n, g.block_size, xb.shape[0]
     ident = monoid_identity(monoid, xb.dtype)
+    groups = _dense_routing(g)                      # the single-query order
     block_dst, block_w = dense_block_view(g)        # shared: decoded once
-    edge_dst = block_dst.reshape(-1)
+
+    def spread(v, fill):  # (B, n) values at each group's owners -> (B, slots)
+        return _cat([
+            _pack_blocks(
+                jnp.take(v, own, axis=1, mode="fill", fill_value=fill), w, fb, axis=1
+            ).reshape(B, -1)
+            for _, own, w in groups
+        ], axis=1)
+
+    edge_dst = _slot_rows(block_dst, groups, n)
     valid = edge_dst < jnp.int32(n)
     ids = jnp.where(valid, edge_dst, jnp.int32(n))  # shared scatter routing
     ea = _edge_active_view(g, edge_active)
     if ea is not None:
-        valid = valid & ea.reshape(-1)
-    frontier_blk = jnp.take(
-        frontier_masks, g.block_src, axis=1, mode="fill", fill_value=False
-    )                                               # (B, NB)
-    act = (frontier_blk[:, :, None] & valid.reshape(NB, FB)[None]).reshape(B, -1)
-    xs_blk = jnp.take(xb, g.block_src, axis=1, mode="fill", fill_value=ident)
-    xs = jnp.broadcast_to(xs_blk[:, :, None], (B, NB, FB)).reshape(B, -1)
-    vals = map_fn(xs, block_w.reshape(-1)[None, :])
+        valid = valid & _slot_rows(ea, groups, False)
+    act = spread(frontier_masks, False) & valid[None]
+    xs = spread(xb, ident)
+    vals = map_fn(xs, _slot_rows(block_w, groups, 0.0)[None, :])
     if map_lanes is not None:
         vals = jnp.where(map_lanes[:, None], vals, xs)
     vals = jnp.where(act, vals, ident)

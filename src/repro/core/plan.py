@@ -56,6 +56,7 @@ import numpy as np
 
 from ..obs import exp_buckets, get_registry
 from ..tuning.defaults import DEFAULT_CHUNK_BLOCKS, DEFAULT_DENSE_FRAC
+from .bands import band_index
 from .compressed import CompressedCSR, exception_dense
 from .csr import CSRGraph, graph_spec, sharded_block_counts
 from .graph_filter import edge_active_words
@@ -158,7 +159,8 @@ def compact_live_blocks(g, edge_active):
       per-block independence — the delta rows gather by live id and the COO
       exception list is filtered to live blocks and re-keyed to compacted
       positions; the whole-graph ``exception_dense`` verdict is pinned as
-      the hint, as in ``shard``.
+      the hint, as in ``shard``.  A band index is rebuilt for the live
+      blocks.
     * ``words_live`` — the packed filter words for the surviving rows
       (uint32[k, F_B/32], aligned 1:1 with ``g_live``'s blocks).
     * ``live_ids``   — int32[k] original block ids, the audit trail that
@@ -203,6 +205,7 @@ def compact_live_blocks(g, edge_active):
             ),
             exception_dense_hint=exception_dense(g),
         )
+        valid = np.asarray(g.valid_count)[live]
     elif isinstance(g, CSRGraph):
         NB, FB = g.num_blocks, g.block_size
         g_live = dataclasses.replace(
@@ -213,9 +216,12 @@ def compact_live_blocks(g, edge_active):
             edge_w=g.edge_w.reshape(NB, FB)[live_ids].reshape(-1),
             num_blocks=int(live.size),
         )
+        valid = (np.asarray(g_live.edge_dst).reshape(-1, FB) < g.n).sum(axis=1)
     else:
         raise TypeError(f"cannot compact {type(g).__name__}")
-    return g_live, words_live, live_ids
+    # the block set changed: a fresh band index, never the stale one
+    bands = None if g.bands is None else band_index(valid, g_live.block_src, g.block_size)
+    return dataclasses.replace(g_live, bands=bands), words_live, live_ids
 
 
 def shard_edge_active(

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..checkpoint import ckpt
+from ..core.bands import band_index
 from ..core.compressed import CompressedCSR, compress
 from ..core.csr import build_csr
 from ..obs import get_registry
@@ -146,7 +147,8 @@ def load_compacted(
     ``step=None`` loads the latest *published* step — unpublished
     ``.tmp`` directories from a crashed save are invisible, which is the
     crash-safety contract: recovery sees the pre-compaction graph until
-    the moment the post-compaction save's ``os.replace`` lands.
+    the moment the post-compaction save's ``os.replace`` lands.  The dense
+    sweep's band index is not saved: it is rebuilt from the valid counts.
     """
     if step is None:
         step = ckpt.latest_step(ckpt_dir)
@@ -156,6 +158,7 @@ def load_compacted(
     tree = ckpt.restore(ckpt_dir, step, example)
     meta = json.loads(bytes(tree["meta"]))
     weighted = bool(meta["weighted"])
+    block_src = jnp.asarray(tree["block_src"], jnp.int32)
     c = CompressedCSR(
         block_first=jnp.asarray(tree["block_first"], jnp.int32),
         deltas=jnp.asarray(tree["deltas"], jnp.uint16),
@@ -163,7 +166,7 @@ def load_compacted(
         exc_block=jnp.asarray(tree["exc_block"], jnp.int32),
         exc_slot=jnp.asarray(tree["exc_slot"], jnp.int32),
         exc_value=jnp.asarray(tree["exc_value"], jnp.int32),
-        block_src=jnp.asarray(tree["block_src"], jnp.int32),
+        block_src=block_src,
         degrees=jnp.asarray(tree["degrees"], jnp.int32),
         n=int(meta["n"]),
         m=int(meta["m"]),
@@ -174,5 +177,6 @@ def load_compacted(
             jnp.asarray(tree["block_weights"], jnp.float32) if weighted else None
         ),
         weighted=weighted,
+        bands=band_index(tree["valid_count"], block_src, int(meta["block_size"])),
     )
     return c, step

@@ -13,6 +13,7 @@ this file.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import re
 
@@ -22,11 +23,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import CompressedCSR
+from repro.core.bands import BandIndex
 from repro.core.csr import graph_spec
 from repro.core.edgemap import edgemap_reduce, edgemap_reduce_batched
 from repro.kernels.compressed_spmv.compressed_spmv import (
     compressed_chunked_spmv_pallas,
 )
+from repro.obs import Registry, use_registry
 from repro.tuning.defaults import (
     DEFAULT_CHUNK_BLOCKS,
     DEFAULT_TILE_BLOCKS,
@@ -41,6 +44,10 @@ FB = 128
 NUM_BLOCKS = 1_578_479
 NUM_EXCEPTIONS = 3_985_003
 SERVE_WIDTH = 8  # the widest cohort chip_smoke.py found fitting at scale 21
+# degree bands of the Graph500 scale-21 graph (graph_seed 500), built on a
+# host: widths 8..128, blocks per band
+BAND_WIDTHS = (8, 16, 32, 64, 128)
+BAND_SIZES = (780_922, 102_217, 171_750, 66_414, 456_516)
 HBM = HARDWARE_PEAKS["TPU v5 lite"]["hbm_bytes"]
 
 
@@ -155,15 +162,61 @@ def test_batched_dense_round_fits_at_serving_width(one_chip):
     assert _footprint(compiled) <= HBM
 
 
-def _while_bodies(hlo: str) -> dict[str, list[str]]:
-    """Each while-loop body computation of an HLO module: name -> lines."""
+def test_banded_dense_round_scatters_band_slots_only(one_chip):
+    """A dense round over a graph with degree bands: every scatter's update
+    operand holds the bands' packed slots, under two fifths of the padded
+    ones; no band's trimmed (rows, width) array is laid out on the way; and
+    the round fits the chip."""
+    nb = sum(BAND_SIZES)
+    s = lambda shape, dt: _spec(shape, dt, one_chip)  # noqa: E731
+    bands = BandIndex(
+        block_ids=s((nb,), jnp.int32), owners=s((nb,), jnp.int32),
+        widths=BAND_WIDTHS, sizes=BAND_SIZES,
+    )
+    g = jax.tree.map(lambda a: s(a.shape, a.dtype), graph_spec(N, nb, FB))
+    g = dataclasses.replace(g, bands=bands)
+    reg = Registry()
+    with use_registry(reg):
+        compiled = jax.jit(
+            lambda gg, fr, x: edgemap_reduce(gg, fr, x, monoid="min", mode="dense")
+        ).lower(g, s((N,), jnp.bool_), s((N,), jnp.int32)).compile()
+    slots = reg.get("sage_dense_sweep_slots")
+    scattered = int(slots.value(kind="scattered"))
+    assert scattered < 0.4 * slots.value(kind="padded")
+    # the 1-D operands of the computations that scatter: the ids and the
+    # updates, beside the n + 1 output rows
+    comps = _computations(compiled.as_text())
+    entry = "\n".join(next(lines for h, lines in comps.items() if h.startswith("ENTRY")))
+    sizes = {
+        int(k)
+        for header, lines in comps.items()
+        if any(" scatter(" in line for line in lines)
+        for k in re.findall(r": \w+\[(\d+)\]", header)
+    }
+    assert sizes - {N + 1} == {scattered}
+    # a band's rows are packed F_B // w to a row before the scatter: no
+    # (rows, w) array of a whole band is laid out, padded to 128 lanes
+    for w, r in zip(BAND_WIDTHS[:-1], BAND_SIZES):
+        rows = -(-r // (8 * (FB // w))) * 8 * (FB // w)
+        assert not re.search(rf"\[{rows},(?!{FB}\])\d+\]", entry), w
+    assert _footprint(compiled) <= HBM
+
+
+def _computations(hlo: str) -> dict[str, list[str]]:
+    """Each computation of an HLO module: header line -> body lines."""
     comps, cur = {}, None
     for line in hlo.splitlines():
         if line and not line.startswith(" ") and line.rstrip().endswith("{"):
-            cur = line.split(" ")[0]
+            cur = line
             comps[cur] = []
         elif cur is not None:
             comps[cur].append(line)
+    return comps
+
+
+def _while_bodies(hlo: str) -> dict[str, list[str]]:
+    """Each while-loop body computation of an HLO module: name -> lines."""
+    comps = {h.split(" ")[0]: lines for h, lines in _computations(hlo).items()}
     return {b: comps[b] for b in set(re.findall(r"body=(%[\w.\-]+)", hlo))}
 
 
